@@ -155,7 +155,9 @@ def main() -> None:
 
     from benchmarks import _config
     from repro import obs
+    from repro.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     if args.smoke:
         _config.set_smoke(True)
 

@@ -12,8 +12,11 @@ bounded-memory blocks, verifying both against the sequential matcher.
 import sys
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import compile_prosite, synthetic_protein
 from repro.engine import ChunkPolicy, ScanPlan, Scanner
+
+enable_compile_cache()
 
 # The P-loop NTP-binding motif: [AG]-x(4)-G-K-[ST]
 PATTERN = "[AG]-x(4)-G-K-[ST]"

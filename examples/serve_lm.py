@@ -23,6 +23,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.prosite import PROSITE_EXTRA, PROSITE_SAMPLES, synthetic_protein
 from repro.engine import Scanner
 
@@ -30,6 +31,7 @@ BANK = [pid for pid in {**PROSITE_SAMPLES, **PROSITE_EXTRA}]
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--store", default=None,
                     help="artifact store dir (default: a temp dir — use a "

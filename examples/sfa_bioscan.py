@@ -17,6 +17,7 @@ sys.path.insert(0, "src")
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import load_bank, synthetic_protein
 from repro.engine import ChunkPolicy, ScanPlan, Scanner
 
@@ -24,6 +25,7 @@ N_CHUNKS = 16
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--db-size", type=int, default=200)
     ap.add_argument("--len", dest="length", type=int, default=2000)

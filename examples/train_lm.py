@@ -13,6 +13,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.config import (
     HOST_MESH,
     ModelConfig,
@@ -34,6 +35,7 @@ LM_100M = ModelConfig(
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

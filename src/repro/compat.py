@@ -1,10 +1,8 @@
-"""Version-compatibility shims for the jax API surface we depend on.
+"""Mesh and ``shard_map`` helpers shared by src/, tests/ and benchmarks/.
 
-The framework targets current jax, but the pinned CI container carries
-jax 0.4.x, where ``jax.sharding.AxisType`` (and the matching ``axis_types=``
-kwarg of ``jax.make_mesh``) does not exist yet. Every mesh construction in
-src/, tests/ and benchmarks/ goes through :func:`make_mesh` so call-sites
-never branch on the jax version.
+Every mesh construction goes through :func:`make_mesh`, which pins the
+axis types to ``Auto``, and every ``shard_map`` through :func:`shard_map`,
+which turns the replication check off by default.
 """
 
 from __future__ import annotations
@@ -15,42 +13,15 @@ import jax
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str]):
-    """``jax.make_mesh`` with explicit-Auto axis types where supported.
-
-    On jax >= 0.5 meshes default to manual axis types under some configs, so
-    we pin ``AxisType.Auto`` explicitly; on older jax the kwarg (and the enum)
-    don't exist and plain ``make_mesh`` already behaves as Auto.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names),
-            axis_types=(axis_type.Auto,) * len(tuple(axis_names)),
-        )
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
+    """``jax.make_mesh`` with every axis typed ``AxisType.Auto``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+    )
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` across versions.
-
-    jax 0.4.x ships it as ``jax.experimental.shard_map.shard_map`` with the
-    replication check named ``check_rep``; newer jax promotes it to
-    ``jax.shard_map`` and renames the flag ``check_vma``.
-    """
-    fn = getattr(jax, "shard_map", None)
-    if fn is not None:
-        return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as legacy
-
-    return legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=check_vma)
-
-
-def cost_analysis(compiled) -> dict:
-    """``Compiled.cost_analysis()`` as a flat dict on every jax version
-    (jax 0.4.x returns a one-element list of dicts, newer jax a dict)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -17,7 +17,8 @@ stacked ``(P, capacity, n_max)`` state buffers:
      the fused-XLA clmul fold, or the ``kernels.clmul`` Pallas bank kernel
      (bit-identical; the natural pick on a TPU runtime);
   4. membership is the sort-merge of (known ∪ candidates) fingerprints, per
-     pattern, batched by ``vmap`` — one XLA program for the whole bank;
+     pattern, looped over the bank by ``lax.map`` inside the same XLA
+     program (a batched multi-key sort compiles for minutes on a TPU);
   5. per-pattern ``done`` / ``blowup`` / ``collision`` flags come back each
      round. Collided patterns restart alone with the next irreducible
      polynomial (per-pattern retry, applied as one batched scatter: the
@@ -208,7 +209,7 @@ def _merge(
     *, tile: int, n: int, k: int, capacity: int,
 ):
     """Stages 4/5: sort-merge membership, exactness check, state append and
-    δ_s rows — one pattern; vmapped over the bank axis."""
+    δ_s rows — one pattern; mapped over the bank axis."""
     C = capacity
     total = C + tile * k
     known_valid = jnp.arange(C, dtype=jnp.int32) < n_states
@@ -317,10 +318,13 @@ def _bucket_round(tables, states, fp_hi, fp_lo, delta, n_states, frontier,
     else:
         fp = jax.vmap(_fold_words)(words, weights, limbs)
     merge = functools.partial(_merge, tile=tile, n=n, k=k, capacity=capacity)
-    return jax.vmap(merge)(
+    # One pattern at a time (``lax.map``), not ``vmap``: the TPU compiler
+    # takes over a minute for the batched multi-key sort at bucket size 23
+    # and a few seconds for the 1-D sort inside a loop.
+    return jax.lax.map(lambda args: merge(*args), (
         states, fp_hi, fp_lo, delta, n_states, frontier, active,
         cand, cand_valid, fp[..., 0], fp[..., 1],
-    )
+    ))
 
 
 # --------------------------------------------------------------------------

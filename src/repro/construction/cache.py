@@ -384,6 +384,11 @@ class RoundCompileCache:
     def __len__(self) -> int:
         return len(self._entries)
 
+    def items(self) -> list:
+        """Snapshot of the ``(key, executable)`` pairs, oldest first."""
+        with self._lock:
+            return list(self._entries.items())
+
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()

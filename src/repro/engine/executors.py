@@ -336,7 +336,7 @@ def bank_doc_mappings_sfa(deltas: jnp.ndarray, sfa_maps: jnp.ndarray,
 
 
 def bank_doc_mappings_pallas(tables: jnp.ndarray, corpus: jnp.ndarray,
-                             n_chunks: int = 8, *, block_b: int = 8,
+                             n_chunks: int = 8, *, block_b: int = 128,
                              interpret: bool | None = None) -> jnp.ndarray:
     """Enumeration doc mappings with the Pallas multi-automaton kernel as the
     chunk-function inner loop: (P, n, k), (D, L) -> (P, D, n). The kernel's
@@ -354,18 +354,18 @@ def bank_doc_mappings_pallas(tables: jnp.ndarray, corpus: jnp.ndarray,
 
 def bank_doc_mappings_sfa_pallas(deltas: jnp.ndarray, sfa_maps: jnp.ndarray,
                                  corpus: jnp.ndarray, n_chunks: int = 8, *,
-                                 block_b: int = 8,
+                                 block_b: int = 128,
                                  interpret: bool | None = None) -> jnp.ndarray:
     """SFA-mode doc mappings through the same Pallas kernel: the SFA delta
-    *is* a DFA table, so the kernel computes each chunk's transition function
-    over SFA states; row 0 (the identity start) is the chunk's final SFA
-    state, and the mapping stack turns it into the DFA-state function."""
+    *is* a DFA table, so the kernel runs each chunk from SFA state 0 (the
+    identity start) alone to its final SFA state, and the mapping stack
+    turns that into the DFA-state function."""
     from ..kernels import ops
 
     D, L = corpus.shape
     chunks = corpus.reshape(D * n_chunks, L // n_chunks)
-    fns = ops.match_bank_chunks(deltas, chunks, block_b=block_b,
-                                interpret=interpret)   # (P, D*n_chunks, S)
+    fns = ops.match_bank_chunks(deltas, chunks, starts=1, block_b=block_b,
+                                interpret=interpret)   # (P, D*n_chunks, 1)
     finals = fns[..., 0]                               # (P, D*n_chunks)
     mapped = jax.vmap(lambda m, f: m[f])(sfa_maps, finals)
     Pn, _, n = mapped.shape

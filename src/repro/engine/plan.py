@@ -321,7 +321,8 @@ class ScanPlan:
         bit-identical results; they differ only in execution strategy.
     ``distribution``
         ``"local"`` or ``"shard_map"`` (documents shard over ``data_axis``
-        of ``mesh``; a 1-device mesh is built when ``mesh`` is None).
+        of ``mesh``; a mesh over all devices is built when ``mesh`` is
+        None, as construction's is).
     ``chunking``
         a :class:`ChunkPolicy`.
     ``construction``

@@ -30,6 +30,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Mapping, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -385,7 +386,7 @@ class Scanner:
             mesh = None
             if plan.distribution == "shard_map":
                 mesh = plan.mesh if plan.mesh is not None else make_mesh(
-                    (1,), (plan.data_axis,)
+                    (jax.device_count(),), (plan.data_axis,)
                 )
 
             groups = []

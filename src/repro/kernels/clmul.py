@@ -8,13 +8,17 @@ at once — per-fingerprint cost is amortized across VPU lanes instead of
 per-instruction silicon.
 
 Layout (the paper's §III-B3 locality argument, restated for VMEM):
-  - the packed word block ``(block_b, W)`` streams HBM→VMEM once per block;
-  - the fold constants ``x^(32 i) mod P`` (W × 2 u32) and the Barrett
-    constants are tiny and stay VMEM-resident across the whole grid;
-  - each block writes a ``(block_b, 2)`` fingerprint tile.
-
-Block size is chosen so ``block_b × W × 4`` bytes plus the 3 accumulator
-copies fit comfortably in VMEM (≤ ~2 MB by default).
+  - words arrive word-major, ``(W8, block_b)``: the batch rides the 128
+    lanes and the ``W`` packed words the sublanes, zero-padded to ``W8``, a
+    multiple of 8 (a zero word adds a zero product, so the padding is exact);
+  - the fold constants ``x^(32 i) mod P`` are a ``(W8, 2)`` column block,
+    broadcast across lanes, and the Barrett limbs a ``(1, 4)`` block; both
+    stay VMEM-resident across a pattern's whole row of blocks;
+  - the XOR fold over words is a loop of aligned 8-row slices followed by a
+    three-step sublane tree, so the kernel needs no ``reduce`` primitive
+    (Mosaic lowers none for XOR);
+  - each block writes a ``(2, block_b)`` [hi; lo] fingerprint tile, which
+    the wrapper transposes back to ``(B, 2)``.
 """
 
 from __future__ import annotations
@@ -41,52 +45,14 @@ def _clmul32_block(a: jnp.ndarray, b: jnp.ndarray) -> tuple:
     return hi, lo
 
 
-def _fold_block(words, weights, c):
-    """Fold + Barrett-reduce one block: (Bb, W) words with (W, 2) fold
-    constants and (4,) Barrett limbs -> ((Bb,) hi, (Bb,) lo)."""
-    w_hi = weights[..., 0][None]      # (1, W)
-    w_lo = weights[..., 1][None]
-
-    # Fold: 96-bit partial products, XOR-reduced over the word axis.
-    p_lo_h, p_lo_l = _clmul32_block(words, jnp.broadcast_to(w_lo, words.shape))
-    p_hi_h, p_hi_l = _clmul32_block(words, jnp.broadcast_to(w_hi, words.shape))
-
-    def xred(x):
-        return jax.lax.reduce(x, jnp.zeros((), x.dtype), jax.lax.bitwise_xor, (1,))
-
-    l0 = xred(p_lo_l)                 # (Bb,)
-    l1 = xred(p_lo_h ^ p_hi_l)
-    l2 = xred(p_hi_h)
-
-    # Barrett reduction with constants [p_hi, p_lo, mu_hi, mu_lo].
-    p = (jnp.broadcast_to(c[0], l2.shape), jnp.broadcast_to(c[1], l2.shape))
-    mu = (jnp.broadcast_to(c[2], l2.shape), jnp.broadcast_to(c[3], l2.shape))
-
-    zeros = jnp.zeros_like(l2)
-    t1pre = (zeros, l2)
-    m3, m2 = _clmul64_hi(t1pre, mu)
-    t2pre = (t1pre[0] ^ m3, t1pre[1] ^ m2)
-    q1, q0 = _clmul64_lo(t2pre, p)
-    return l1 ^ q1, l0 ^ q0
-
-
-def _fingerprint_kernel(words_ref, weights_ref, consts_ref, out_ref):
-    hi, lo = _fold_block(words_ref[...], weights_ref[...], consts_ref[...])
-    out_ref[..., 0] = hi
-    out_ref[..., 1] = lo
-
-
 def _clmul64_hi(a: tuple, b: tuple) -> tuple:
     """High 64 bits (limbs 3, 2) of a 64×64 carry-less product."""
     ah, al = a
     bh, bl = b
-    ll_h, _ = _clmul32_block(al, bl)
-    lh_h, lh_l = _clmul32_block(al, bh)
-    hl_h, hl_l = _clmul32_block(ah, bl)
+    lh_h, _ = _clmul32_block(al, bh)
+    hl_h, _ = _clmul32_block(ah, bl)
     hh_h, hh_l = _clmul32_block(ah, bh)
-    l2 = lh_h ^ hl_h ^ hh_l
-    l3 = hh_h
-    return l3, l2
+    return hh_h, lh_h ^ hl_h ^ hh_l
 
 
 def _clmul64_lo(a: tuple, b: tuple) -> tuple:
@@ -96,41 +62,97 @@ def _clmul64_lo(a: tuple, b: tuple) -> tuple:
     ll_h, ll_l = _clmul32_block(al, bl)
     _, lh_l = _clmul32_block(al, bh)
     _, hl_l = _clmul32_block(ah, bl)
-    l0 = ll_l
-    l1 = ll_h ^ lh_l ^ hl_l
-    return l1, l0
+    return ll_h ^ lh_l ^ hl_l, ll_l
+
+
+def _xor_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """XOR-fold a (8·m, N) block over its rows -> (1, N): aligned 8-row
+    slices first, then a sublane tree 8 -> 4 -> 2 -> 1."""
+    acc = x[0:8]
+    for r in range(8, x.shape[0], 8):
+        acc = acc ^ x[r:r + 8]
+    acc = acc[0:4] ^ acc[4:8]
+    acc = acc[0:2] ^ acc[2:4]
+    return acc[0:1] ^ acc[1:2]
+
+
+def _fingerprint_kernel(words_ref, weights_ref, limbs_ref, out_ref):
+    """One (pattern, block) cell: (W8, Bb) words -> (2, Bb) [hi; lo]."""
+    words = words_ref[0]                            # (W8, Bb) u32
+    w = weights_ref[0]                              # (W8, 2)
+    c = limbs_ref[0]                                # (1, 4)
+    shape = words.shape
+    p_lo_h, p_lo_l = _clmul32_block(words, jnp.broadcast_to(w[:, 1:2], shape))
+    p_hi_h, p_hi_l = _clmul32_block(words, jnp.broadcast_to(w[:, 0:1], shape))
+    l0 = _xor_rows(p_lo_l)                          # (1, Bb)
+    l1 = _xor_rows(p_lo_h ^ p_hi_l)
+    l2 = _xor_rows(p_hi_h)
+
+    # Barrett reduction with constants [p_hi, p_lo, mu_hi, mu_lo].
+    row = l2.shape
+
+    def limb(i):
+        return jnp.broadcast_to(c[:, i:i + 1], row)
+
+    zeros = jnp.zeros(row, jnp.uint32)
+    m3, m2 = _clmul64_hi((zeros, l2), (limb(2), limb(3)))
+    q1, q0 = _clmul64_lo((m3, l2 ^ m2), (limb(0), limb(1)))
+    out_ref[0] = jnp.concatenate([l1 ^ q1, l0 ^ q0], axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
+def fingerprint_bank_pallas(
+    words: jnp.ndarray,
+    weights: jnp.ndarray,
+    consts_limbs: jnp.ndarray,
+    *,
+    block_b: int = 256,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Per-pattern batched fingerprints.
+
+    words: (P, B, W) u32; weights: (P, W, 2) u32 per-pattern fold constants;
+    consts_limbs: (P, 4) u32 per-pattern Barrett constants -> (P, B, 2) u32.
+    Grid (pattern, block): pattern p's constants load once per row of blocks.
+    On a TPU ``block_b`` must be a multiple of 128 unless it covers all of B.
+    """
+    P, B, W = words.shape
+    block_b = min(block_b, B)
+    Bp = -(-B // block_b) * block_b
+    W8 = -(-W // 8) * 8
+    words_t = jnp.pad(jnp.swapaxes(words, 1, 2),
+                      ((0, 0), (0, W8 - W), (0, Bp - B)))      # (P, W8, Bp)
+    weights = jnp.pad(weights, ((0, 0), (0, W8 - W), (0, 0)))  # (P, W8, 2)
+    out = pl.pallas_call(
+        _fingerprint_kernel,
+        grid=(P, Bp // block_b),
+        in_specs=[
+            pl.BlockSpec((1, W8, block_b), lambda p, i: (p, 0, i)),
+            pl.BlockSpec((1, W8, 2), lambda p, i: (p, 0, 0)),
+            pl.BlockSpec((1, 1, 4), lambda p, i: (p, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 2, block_b), lambda p, i: (p, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((P, 2, Bp), jnp.uint32),
+        interpret=interpret,
+    )(words_t, weights, consts_limbs[:, None, :])
+    return jnp.swapaxes(out[:, :, :B], 1, 2)
+
+
 def fingerprint_pallas(
     words: jnp.ndarray,
     weights: jnp.ndarray,
     consts_limbs: jnp.ndarray,
     *,
     block_b: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
-    """Batched fingerprints. words: (B, W) u32; weights: (W, 2) u32;
-    consts_limbs: (4,) u32 [p_hi, p_lo, mu_hi, mu_lo] -> (B, 2) u32."""
-    B, W = words.shape
-    block_b = min(block_b, B)
-    if B % block_b:
-        pad = block_b - B % block_b
-        words = jnp.pad(words, ((0, pad), (0, 0)))
-    grid = (words.shape[0] // block_b,)
-    out = pl.pallas_call(
-        _fingerprint_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, W), lambda i: (i, 0)),
-            pl.BlockSpec((W, 2), lambda i: (0, 0)),
-            pl.BlockSpec((4,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_b, 2), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((words.shape[0], 2), jnp.uint32),
-        interpret=interpret,
-    )(words, weights, consts_limbs)
-    return out[:B]
+    """Batched fingerprints under one polynomial: the bank kernel with P=1.
+    words: (B, W) u32; weights: (W, 2) u32; consts_limbs: (4,) u32
+    [p_hi, p_lo, mu_hi, mu_lo] -> (B, 2) u32."""
+    return fingerprint_bank_pallas(
+        words[None], weights[None], consts_limbs[None],
+        block_b=block_b, interpret=interpret,
+    )[0]
 
 
 def consts_limbs_of(consts: BarrettConstants) -> jnp.ndarray:
@@ -143,59 +165,3 @@ def consts_limbs_of(consts: BarrettConstants) -> jnp.ndarray:
         ],
         dtype=jnp.uint32,
     )
-
-
-# --------------------------------------------------------------------------
-# Bank variant: the fold batched over the pattern axis
-# --------------------------------------------------------------------------
-#
-# Batched construction (repro.construction.batched) fingerprints every
-# pattern's candidate tile each round, and each pattern carries its *own*
-# fold/Barrett constants (per-pattern polynomial retry re-randomizes one
-# pattern's P(t) without touching the others). The bank kernel adds the
-# pattern axis to the grid: cell (p, i) folds block i of pattern p with
-# pattern p's constants, which stay VMEM-resident across that pattern's
-# whole block row — the same residency argument as the multi-automaton
-# match kernel.
-
-
-def _fingerprint_bank_kernel(words_ref, weights_ref, consts_ref, out_ref):
-    hi, lo = _fold_block(words_ref[0], weights_ref[0], consts_ref[0])
-    out_ref[0, :, 0] = hi
-    out_ref[0, :, 1] = lo
-
-
-@functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
-def fingerprint_bank_pallas(
-    words: jnp.ndarray,
-    weights: jnp.ndarray,
-    consts_limbs: jnp.ndarray,
-    *,
-    block_b: int = 256,
-    interpret: bool = True,
-) -> jnp.ndarray:
-    """Per-pattern batched fingerprints.
-
-    words: (P, B, W) u32; weights: (P, W, 2) u32 per-pattern fold constants;
-    consts_limbs: (P, 4) u32 per-pattern Barrett constants -> (P, B, 2) u32.
-    Grid (pattern, block): pattern p's constants load once per row of blocks.
-    """
-    P, B, W = words.shape
-    block_b = min(block_b, B)
-    if B % block_b:
-        pad = block_b - B % block_b
-        words = jnp.pad(words, ((0, 0), (0, pad), (0, 0)))
-    grid = (P, words.shape[1] // block_b)
-    out = pl.pallas_call(
-        _fingerprint_bank_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_b, W), lambda p, i: (p, i, 0)),
-            pl.BlockSpec((1, W, 2), lambda p, i: (p, 0, 0)),
-            pl.BlockSpec((1, 4), lambda p, i: (p, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_b, 2), lambda p, i: (p, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((P, words.shape[1], 2), jnp.uint32),
-        interpret=interpret,
-    )(words, weights, consts_limbs)
-    return out[:, :B]

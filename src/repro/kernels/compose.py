@@ -4,15 +4,18 @@ The SFA monoid combine ``out[b, q] = g[b, f[b, q]]`` is a gather — latency
 bound and VPU-serial on TPU. For the state-vector sizes the paper works with
 (n ≤ a few thousand), re-expressing the gather as
 
-    out[b, q] = Σ_j onehot(f)[b, q, j] · g[b, j]
+    out[b, q] = Σ_j g[b, j] · onehot(f)[b, j, q]
 
 turns it into an MXU contraction: n² MACs replace n dependent loads, and the
 MXU's 128×128 systolic throughput makes that trade profitable for n ≥ ~128.
-State ids are < 2^24, so f32 accumulation is exact and the kernel is
-bit-exact against the gather oracle.
+State ids are < 2^24 and the contraction runs at ``Precision.HIGHEST``, so
+f32 accumulation is exact and the kernel is bit-exact against the gather
+oracle.
 
-Grid: (batch, q-tiles). Per cell the kernel holds a ``(block_q, n)`` one-hot
-tile and the full ``g`` row in VMEM — ≤ ~3 MB at n = 2930, block_q = 256.
+Grid: (row blocks, q-tiles). Per cell the kernel holds an ``(8, block_q)``
+tile of ``f``, the ``(8, n)`` rows of ``g``, and one ``(n, block_q)``
+one-hot at a time in VMEM — ≤ ~3 MB at n = 2930, block_q = 256. On a TPU
+``block_q`` is a multiple of 128 (or all of n).
 """
 
 from __future__ import annotations
@@ -23,20 +26,25 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+#: Rows of ``f``/``g`` per grid cell: one sublane tile.
+_ROWS = 8
+
 
 def _compose_kernel(f_ref, g_ref, out_ref):
-    f = f_ref[...]                      # (1, block_q) int32
-    g = g_ref[...]                      # (1, n) int32
+    f = f_ref[...]                                         # (bb, bq) int32
+    g = g_ref[...].astype(jnp.float32)                     # (bb, n)
+    bb, bq = f.shape
     n = g.shape[-1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (f.shape[-1], n), 1)
-    onehot = (f[0][:, None] == iota).astype(jnp.float32)   # (block_q, n)
-    vals = jax.lax.dot_general(
-        onehot,
-        g[0].astype(jnp.float32)[:, None],                 # (n, 1)
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                      # (block_q, 1)
-    out_ref[...] = vals[:, 0].astype(jnp.int32)[None]
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n, bq), 0)
+    rows = []
+    for r in range(bb):
+        onehot = (iota == f[r:r + 1]).astype(jnp.float32)  # (n, bq)
+        rows.append(jax.lax.dot_general(
+            g[r:r + 1], onehot, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        ))                                                 # (1, bq)
+    out_ref[...] = jnp.concatenate(rows, axis=0).astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
@@ -45,24 +53,25 @@ def compose_pallas(
     g: jnp.ndarray,
     *,
     block_q: int = 256,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Composition combine. f, g: (B, n) int32 -> (B, n) int32 (f then g)."""
     B, n = f.shape
+    block_b = min(_ROWS, B)
     block_q = min(block_q, n)
-    padded_n = -(-n // block_q) * block_q
-    if padded_n != n:
-        f = jnp.pad(f, ((0, 0), (0, padded_n - n)))
-    grid = (B, padded_n // block_q)
+    Bp = -(-B // block_b) * block_b
+    Np = -(-n // block_q) * block_q
+    f = jnp.pad(f, ((0, Bp - B), (0, Np - n)))
+    g = jnp.pad(g, ((0, Bp - B), (0, 0)))
     out = pl.pallas_call(
         _compose_kernel,
-        grid=grid,
+        grid=(Bp // block_b, Np // block_q),
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b, q: (b, q)),
-            pl.BlockSpec((1, n), lambda b, q: (b, 0)),
+            pl.BlockSpec((block_b, block_q), lambda b, q: (b, q)),
+            pl.BlockSpec((block_b, n), lambda b, q: (b, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q), lambda b, q: (b, q)),
-        out_shape=jax.ShapeDtypeStruct((B, padded_n), jnp.int32),
+        out_specs=pl.BlockSpec((block_b, block_q), lambda b, q: (b, q)),
+        out_shape=jax.ShapeDtypeStruct((Bp, Np), jnp.int32),
         interpret=interpret,
     )(f, g)
-    return out[:, :n]
+    return out[:B, :n]

@@ -10,17 +10,21 @@ bound and VPU-serial, exactly like the composition combine in
 ``kernels/compose.py``. The same one-hot re-expression applies: per frontier
 row ``t``,
 
-    onehot(ft[t]) (n, n) @ table (n, k)  ->  (n, k)
+    tableᵀ (k, n) @ onehot(ft[t]) (n, n)  ->  cand rows t·k … t·k+k-1 (k, n)
 
-turns the ``n·k`` dependent loads into one MXU contraction whose systolic
-throughput wins for ``n ≥ ~128``. State ids are < 2^16 (the batched engine's
-packing bound), far under f32's 2^24 exact-integer range, so the matmul is
-bit-exact against the gather oracle — the property the construction tests
-pin (``expand_backend="pallas"`` must be bit-identical to the XLA gather).
+turns the ``n·k`` dependent loads into one MXU contraction. State ids are
+< 2^16 (the batched engine's packing bound), far under f32's 2^24
+exact-integer range, and the contraction runs at ``Precision.HIGHEST``, so
+the matmul is bit-exact against the gather oracle — the property the
+construction tests pin (``expand_backend="pallas"`` must be bit-identical to
+the XLA gather).
 
-Grid: (pattern, frontier-tile blocks). Per cell the kernel holds a
-``(block_t, n, n)`` one-hot stack and the pattern's full ``(n, k)`` table in
-VMEM; ``block_t`` auto-shrinks with ``n`` to bound the one-hot residency.
+Grid: (pattern, frontier-tile blocks). Per cell the kernel loops over its
+``block_t`` frontier rows, holding one ``(n, n)`` one-hot at a time and the
+pattern's ``(k, n)`` transposed table in VMEM; each row's ``(k, n)`` result
+lands on a leading (untiled) axis of a ``(T, k, n)`` output, which the
+wrapper views as ``(T·k, n)`` at no cost. ``block_t`` auto-shrinks with
+``k·n`` to bound the output block's residency.
 """
 
 from __future__ import annotations
@@ -31,33 +35,35 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-#: One-hot residency bound: block_t · n · n f32 elements per grid cell
-#: (~2 MB at the cap). ``_auto_block_t`` shrinks block_t to honor it.
-_ONEHOT_BUDGET = 1 << 19
+#: Output-block residency bound: block_t · k · n int32 elements per grid
+#: cell (~1 MB at the cap). ``_auto_block_t`` shrinks block_t to honor it.
+_OUT_BUDGET = 1 << 18
 
 
-def _auto_block_t(tile: int, n: int) -> int:
-    """Largest divisor of ``tile`` whose one-hot stack fits the budget."""
-    bt = max(1, min(tile, _ONEHOT_BUDGET // max(1, n * n)))
+def _auto_block_t(tile: int, row_elems: int) -> int:
+    """Largest divisor of ``tile`` whose output block fits the budget."""
+    bt = max(1, min(tile, _OUT_BUDGET // max(1, row_elems)))
     while tile % bt:
         bt -= 1
     return bt
 
 
-def _expand_kernel(ft_ref, table_ref, out_ref):
-    ft = ft_ref[0]                                   # (bt, n) int32
-    table = table_ref[0].astype(jnp.float32)         # (n, k)
-    bt, n = ft.shape
-    k = table.shape[-1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (bt, n, n), 2)
-    onehot = (ft[:, :, None] == iota).astype(jnp.float32)    # (bt, n, n)
-    vals = jax.lax.dot_general(
-        onehot, table, (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                # (bt, n, k)
-    # Row-major (frontier, symbol) candidate order — the layout the
-    # sort-merge's delta scatter-back assumes.
-    out_ref[0] = jnp.swapaxes(vals, 1, 2).reshape(bt * k, n).astype(jnp.int32)
+def _expand_kernel(ft_ref, table_t_ref, out_ref):
+    table_t = table_t_ref[0].astype(jnp.float32)     # (k, n)
+    _, bt, n = ft_ref.shape
+    iota = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+
+    def row(t, carry):
+        f = ft_ref[0, pl.ds(t, 1), :]                # (1, n)
+        onehot = (iota == f).astype(jnp.float32)     # [j, q] = ft[t, q] == j
+        out_ref[0, t] = jax.lax.dot_general(
+            table_t, onehot, (((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        ).astype(jnp.int32)                          # (k, n)
+        return carry
+
+    jax.lax.fori_loop(0, bt, row, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
@@ -66,7 +72,7 @@ def expand_bank_pallas(
     ft: jnp.ndarray,
     *,
     block_t: int = 0,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jnp.ndarray:
     """Frontier expansion over a bank: (B, n, k) tables, (B, T, n) frontier
     tiles -> (B, T·k, n) candidates in row-major (frontier, symbol) order.
@@ -75,18 +81,18 @@ def expand_bank_pallas(
     B, T, n = ft.shape
     k = tables.shape[-1]
     if block_t <= 0:
-        block_t = _auto_block_t(T, n)
+        block_t = _auto_block_t(T, k * n)
     if T % block_t:
         raise ValueError(f"block_t ({block_t}) must divide the tile ({T})")
-    grid = (B, T // block_t)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _expand_kernel,
-        grid=grid,
+        grid=(B, T // block_t),
         in_specs=[
             pl.BlockSpec((1, block_t, n), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, n, k), lambda b, t: (b, 0, 0)),
+            pl.BlockSpec((1, k, n), lambda b, t: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_t * k, n), lambda b, t: (b, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, T * k, n), jnp.int32),
+        out_specs=pl.BlockSpec((1, block_t, k, n), lambda b, t: (b, t, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, T, k, n), jnp.int32),
         interpret=interpret,
-    )(ft, tables)
+    )(ft, jnp.swapaxes(tables, 1, 2))
+    return out.reshape(B, T * k, n)

@@ -1,9 +1,10 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On this CPU container the kernels always run with ``interpret=True`` (the
-kernel body executes step-by-step on CPU, validating semantics); on a real
-TPU runtime ``interpret=False`` compiles them to Mosaic. The flag defaults
-from the active backend so call-sites never branch.
+On a TPU the kernels compile to Mosaic (``interpret=False``); on the CPU
+backend they run in Pallas interpret mode (the kernel body executes
+step-by-step, validating semantics). The flag defaults from the active
+backend so call-sites never branch, and any other backend is an error: a
+kernel never silently falls back to the interpreter on an accelerator.
 """
 
 from __future__ import annotations
@@ -25,8 +26,17 @@ from .match_scan import match_bank_chunks_pallas, match_chunks_pallas
 @functools.lru_cache(maxsize=None)
 def _default_interpret() -> bool:
     """Cached backend probe: the active platform cannot change mid-process,
-    so ``jax.default_backend()`` (which can trigger backend init) runs once."""
-    return jax.default_backend() != "tpu"
+    so ``jax.default_backend()`` (which can trigger backend init) runs once.
+    True on ``"cpu"``, False on ``"tpu"``; any other platform raises."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"Pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the active backend is {platform!r}"
+    )
 
 
 def _count(name: str) -> None:
@@ -150,7 +160,7 @@ def match_chunks(
     table: jnp.ndarray,
     chunks: jnp.ndarray,
     *,
-    block_b: int = 8,
+    block_b: int = 128,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Per-chunk transition functions: (n, k), (B, L) -> (B, n).
@@ -169,12 +179,14 @@ def match_bank_chunks(
     tables: jnp.ndarray,
     chunks: jnp.ndarray,
     *,
-    block_b: int = 8,
+    starts: int | None = None,
+    block_b: int = 128,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Multi-automaton chunk functions: (P, n, k), (B, L) -> (P, B, n)."""
+    """Multi-automaton chunk functions: (P, n, k), (B, L) -> (P, B, R), the
+    mappings of start states ``0..R-1`` with ``R = starts`` (default n)."""
     if interpret is None:
         interpret = _default_interpret()
     _count("match_bank_chunks")
-    return match_bank_chunks_pallas(tables, chunks, block_b=block_b,
-                                    interpret=interpret)
+    return match_bank_chunks_pallas(tables, chunks, starts=starts,
+                                    block_b=block_b, interpret=interpret)

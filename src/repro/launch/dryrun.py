@@ -121,9 +121,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: Path) -> dict
 
     mem = compiled.memory_analysis()
     print(f"[{arch} x {shape_name}] memory_analysis: {mem}")
-    from repro.compat import cost_analysis
-
-    cost = cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     print(f"[{arch} x {shape_name}] cost_analysis flops={cost.get('flops', 0):.3e} "
           f"bytes={cost.get('bytes accessed', 0):.3e} (while bodies counted once)")
 

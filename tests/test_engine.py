@@ -136,6 +136,44 @@ def test_shard_map_distribution_matches_local():
     assert np.array_equal(local, dist)
 
 
+_DEFAULT_MESH_SCRIPT = """
+import sys
+import jax
+import numpy as np
+from repro.core.prosite import load_bank, synthetic_protein
+from repro.engine import ChunkPolicy, ConstructionPolicy, ScanPlan, Scanner
+
+bank = load_bank(["PS00001", "PS00005", "PS00016", "PS00017"])
+docs = [synthetic_protein(64, seed=i) for i in range(8)]
+plan = ScanPlan(chunking=ChunkPolicy(n_chunks=4),
+                construction=ConstructionPolicy(cache="off"))
+local = Scanner.compile(bank, plan)
+dist = Scanner.compile(bank, plan.with_(distribution="shard_map"))
+assert jax.device_count() == 4, jax.devices()
+assert dist.mesh.size == 4, dist.mesh
+assert np.array_equal(local.scan(docs).hits, dist.scan(docs).hits)
+print("OK")
+"""
+
+
+def test_shard_map_default_mesh_spans_all_devices():
+    """With no mesh given, the scan's shard_map mesh spans every device (4
+    virtual CPU devices in a child process) and matches local bit for bit."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = Path(__file__).resolve().parents[1] / "src"
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4",
+           "PYTHONPATH": str(src)}
+    r = subprocess.run([sys.executable, "-c", _DEFAULT_MESH_SCRIPT],
+                       capture_output=True, text=True, timeout=600, env=env)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert r.stdout.strip().splitlines()[-1] == "OK"
+
+
 def test_bucketed_plan_matches_unbucketed():
     k = 6
     dfas = [random_dfa(n, k, seed=60 + n) for n in (2, 3, 9, 17, 5)]

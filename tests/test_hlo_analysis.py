@@ -6,7 +6,6 @@ import numpy as np
 
 from repro.analysis.hlo import _shape_bytes, analyze_module
 from repro.analysis.roofline import HW, model_flops, roofline_terms
-from repro.compat import cost_analysis
 from repro.config import SHAPES
 from repro.configs import get_config
 
@@ -35,7 +34,7 @@ def test_trip_count_correction_exact():
     st = analyze_module(co.as_text(), 1)
     assert st.flops == 8 * 2 * 64**3
     # raw cost_analysis counts the body once — our whole reason to exist
-    assert cost_analysis(co)["flops"] < st.flops
+    assert co.cost_analysis()["flops"] < st.flops
 
 
 def test_nested_scan_multiplies():

@@ -1,5 +1,7 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, interpret mode."""
 
+import inspect
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -79,3 +81,35 @@ def test_match_kernel_composes_with_compose_kernel():
     )
     composed = ops.compose(m1, m2, interpret=True)
     assert np.array_equal(np.asarray(whole), np.asarray(composed))
+
+
+@pytest.mark.parametrize("name", [
+    "clmul.fingerprint_bank_pallas", "clmul.fingerprint_pallas",
+    "compose.compose_pallas", "expand.expand_bank_pallas",
+    "match_scan.match_bank_chunks_pallas", "match_scan.match_chunks_pallas",
+])
+def test_pallas_entry_points_compile_unless_asked(name):
+    """No kernel entry point interprets unless its caller asks."""
+    import importlib
+
+    mod, fn = name.split(".")
+    f = getattr(importlib.import_module(f"repro.kernels.{mod}"), fn)
+    assert inspect.signature(f).parameters["interpret"].default is False
+
+
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None),
+])
+def test_default_interpret_only_on_cpu(monkeypatch, platform, interpret):
+    """The backend probe interprets on "cpu", compiles on "tpu", and refuses
+    any other platform instead of silently interpreting there."""
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: platform)
+    ops._default_interpret.cache_clear()
+    try:
+        if interpret is None:
+            with pytest.raises(RuntimeError):
+                ops._default_interpret()
+        else:
+            assert ops._default_interpret() is interpret
+    finally:
+        ops._default_interpret.cache_clear()
