@@ -17,8 +17,6 @@ Prints ``name,us_per_call,derived`` CSV. Figure mapping:
   service bench_service               (cold vs warm start through the
           artifact store; coalesced vs sequential submits; writes
           BENCH_service.json)
-  obs     bench_obs                   (observability overhead: warm scans
-          with metrics enabled vs disabled, writes BENCH_obs.json)
 
 ``--smoke`` caps sizes/iterations (see benchmarks/_config.py) so CI can run
 the whole harness as a smoke job without burning minutes on full figures.
@@ -81,7 +79,6 @@ SUITES = [
     ("bench_multipattern", ("run", "run_engine_modes")),
     ("bench_speculative", ("run",)),
     ("bench_service", ("run", "run_coalesced")),
-    ("bench_obs", ("run",)),
 ]
 
 
@@ -231,8 +228,7 @@ def main() -> None:
                 traceback.print_exc()
         summary.append((mod_name, status, wall))
         # The module's metric footprint: what the registry counted while it
-        # ran (bench_obs resets the registry mid-run on purpose — its delta
-        # is the post-reset residue, still useful, just not cumulative).
+        # ran.
         obs.write_jsonl(metrics_path, [obs.snapshot_record(
             obs.snapshot_delta(before, obs.snapshot()), label=mod_name,
         )])

@@ -27,6 +27,8 @@ across all plans — the differential property the test suite pins down.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 from dataclasses import dataclass, field
 from typing import Any, Mapping, Sequence
 
@@ -56,6 +58,12 @@ from .streaming import StreamResult, StreamSession
 obs.counter("engine.compiles", help="Scanner.compile calls")
 obs.counter("engine.scans", help="Scanner scan/census calls")
 obs.counter("engine.docs_scanned", help="documents scanned")
+obs.counter("engine.residues_scanned",
+            help="symbols of the documents scan() matched")
+obs.counter("engine.h2d_bytes",
+            help="bytes of scan inputs sent to the device")
+obs.counter("engine.d2h_bytes",
+            help="bytes of scan results read back from the device")
 obs.counter("speculative.total_chunks",
             help="chunks executed speculatively")
 obs.counter("speculative.hit_chunks",
@@ -324,6 +332,38 @@ class ScanResult:
         return {pid: self.hits[p] for p, pid in enumerate(self.ids)}
 
 
+#: [h2d, d2h] bytes of the ``scan`` call running in this context (None
+#: outside one): ``_count_moved`` adds to it, ``scan`` puts it on its span.
+_scan_moved: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_scan_moved", default=None
+)
+
+
+def _count_moved(span, h2d: int, d2h: int) -> None:
+    """One device round trip's bytes, on its ``scanner.device`` span, on the
+    registry's ``engine.h2d_bytes`` / ``engine.d2h_bytes``, and on the
+    enclosing ``scan`` call's totals."""
+    obs.counter("engine.h2d_bytes").inc(h2d)
+    obs.counter("engine.d2h_bytes").inc(d2h)
+    if span is not None:
+        span.attrs.update(h2d_bytes=h2d, d2h_bytes=d2h)
+    total = _scan_moved.get()
+    if total is not None:
+        total[0] += h2d
+        total[1] += d2h
+
+
+@contextlib.contextmanager
+def _scan_totals():
+    """Collect the bytes moved inside the block -> [h2d, d2h]."""
+    total = [0, 0]
+    token = _scan_moved.set(total)
+    try:
+        yield total
+    finally:
+        _scan_moved.reset(token)
+
+
 # --------------------------------------------------------------------------
 # The facade
 # --------------------------------------------------------------------------
@@ -481,45 +521,53 @@ class Scanner:
         if head_len < L:
             if not maps.flags.writeable:
                 maps = maps.copy()
-            for d in range(D):
-                maps[:, d, :] = X.compose_sequential(
-                    g.bank.tables, maps[:, d, :], corpus[d, head_len:]
-                )
+            with obs.span("scanner.tail", docs=D, symbols=L - head_len):
+                for d in range(D):
+                    maps[:, d, :] = X.compose_sequential(
+                        g.bank.tables, maps[:, d, :], corpus[d, head_len:]
+                    )
         return maps
 
     def _head_mappings(self, g: PatternGroup, head: np.ndarray,
                        n_chunks: int) -> np.ndarray:
+        """Chunk-parallel mappings of the head: one ``scanner.device`` span
+        from the upload to the read-back (none on the reference backend,
+        which never leaves the host)."""
         backend = self.plan.backend
-        corpus_j = jnp.asarray(head)
+        if backend == "reference":
+            return _reference_doc_mappings(g.bank.tables, head)
+        D, L = head.shape
         if self.mesh is not None:
-            D = head.shape[0]
             n_dev = int(np.prod(list(self.mesh.shape.values())))
             if D % n_dev:
                 raise ValueError(
                     f"shard_map distribution needs doc count ({D}) divisible "
                     f"by the mesh's {self.plan.data_axis} size ({n_dev})"
                 )
-            if g.mode == "sfa":
-                out = g._dist_fn(g.deltas, g.sfa_maps, corpus_j)
+        with obs.span("scanner.device", mode=g.mode, patterns=len(g.indices),
+                      docs=D, length=L) as sp:
+            corpus_j = jnp.asarray(head)
+            if self.mesh is not None:
+                if g.mode == "sfa":
+                    out = g._dist_fn(g.deltas, g.sfa_maps, corpus_j)
+                else:
+                    out = g._dist_fn(g.tables, corpus_j)
+            elif backend == "pallas":
+                if g.mode == "sfa":
+                    out = X.bank_doc_mappings_sfa_pallas(
+                        g.deltas, g.sfa_maps, corpus_j, n_chunks
+                    )
+                else:
+                    out = X.bank_doc_mappings_pallas(g.tables, corpus_j,
+                                                     n_chunks)
+            elif g.mode == "sfa":
+                out = X.bank_doc_mappings_sfa(g.deltas, g.sfa_maps, corpus_j,
+                                              n_chunks)
             else:
-                out = g._dist_fn(g.tables, corpus_j)
-            return np.asarray(out)
-        if backend == "reference":
-            return _reference_doc_mappings(g.bank.tables, head)
-        if backend == "pallas":
-            if g.mode == "sfa":
-                out = X.bank_doc_mappings_sfa_pallas(
-                    g.deltas, g.sfa_maps, corpus_j, n_chunks
-                )
-            else:
-                out = X.bank_doc_mappings_pallas(g.tables, corpus_j, n_chunks)
-            return np.asarray(out)
-        # xla
-        if g.mode == "sfa":
-            out = X.bank_doc_mappings_sfa(g.deltas, g.sfa_maps, corpus_j, n_chunks)
-        else:
-            out = X.bank_doc_mappings(g.tables, corpus_j, n_chunks)
-        return np.asarray(out)
+                out = X.bank_doc_mappings(g.tables, corpus_j, n_chunks)
+            maps = np.asarray(out)
+            _count_moved(sp, head.nbytes, maps.nbytes)
+        return maps
 
     # -- the speculative core ----------------------------------------------
 
@@ -643,19 +691,21 @@ class Scanner:
                             f"divisible by the mesh's {self.plan.data_axis} "
                             f"size ({n_dev})"
                         )
-                    out = g._spec_dist_fn(
-                        g.tables, jnp.asarray(spec), jnp.asarray(starts),
-                        jnp.asarray(head),
-                    )
-                else:
-                    out = speculative_bank_finals(
-                        g.tables, jnp.asarray(spec), jnp.asarray(starts),
-                        jnp.asarray(head), n_chunks=n_chunks,
-                        max_rounds=pol.max_repair_rounds,
-                    )
-                finals, resolved, hit_n, repaired, rounds = (
-                    np.asarray(x) for x in out
-                )
+                with obs.span("scanner.device", mode=g.mode, patterns=Pg,
+                              docs=D, length=head_len) as sp:
+                    args = (jnp.asarray(spec), jnp.asarray(starts),
+                            jnp.asarray(head))
+                    if self.mesh is not None:
+                        out = g._spec_dist_fn(g.tables, *args)
+                    else:
+                        out = speculative_bank_finals(
+                            g.tables, *args, n_chunks=n_chunks,
+                            max_rounds=pol.max_repair_rounds,
+                        )
+                    out = [np.asarray(x) for x in out]
+                    _count_moved(sp, spec.nbytes + starts.nbytes + head.nbytes,
+                                 sum(x.nbytes for x in out))
+                finals, resolved, hit_n, repaired, rounds = out
                 stats = SpeculationStats(
                     total_chunks=Pg * D * n_chunks,
                     hit_chunks=int(hit_n),
@@ -666,12 +716,15 @@ class Scanner:
                 if not resolved.all():
                     finals = np.array(finals)  # device views are read-only
                     bad = np.flatnonzero(~resolved.all(axis=0))
-                    with obs.span("speculative.fallback", lanes=len(bad)):
+                    sub = np.ascontiguousarray(head[bad])
+                    with obs.span("speculative.fallback", lanes=len(bad)), \
+                            obs.span("scanner.device", mode="enumeration",
+                                     patterns=Pg, docs=len(bad),
+                                     length=head_len) as sp:
                         maps = np.asarray(X.bank_doc_mappings(
-                            g.tables,
-                            jnp.asarray(np.ascontiguousarray(head[bad])),
-                            n_chunks,
+                            g.tables, jnp.asarray(sub), n_chunks,
                         ))
+                        _count_moved(sp, sub.nbytes, maps.nbytes)
                     exact = np.take_along_axis(
                         maps, starts[:, None, None].astype(np.int64), axis=2
                     )[:, :, 0]
@@ -681,9 +734,10 @@ class Scanner:
             else:
                 finals = np.repeat(starts[:, None], D, axis=1)
             if head_len < L:
-                finals = X.advance_states_sequential(
-                    g.bank.tables, finals, corpus[:, head_len:]
-                )
+                with obs.span("scanner.tail", docs=D, symbols=L - head_len):
+                    finals = X.advance_states_sequential(
+                        g.bank.tables, finals, corpus[:, head_len:]
+                    )
         obs.counter("speculative.total_chunks").inc(stats.total_chunks)
         obs.counter("speculative.hit_chunks").inc(stats.hit_chunks)
         obs.counter("speculative.repaired_chunks").inc(stats.repaired_chunks)
@@ -697,44 +751,55 @@ class Scanner:
 
     def scan(self, docs) -> ScanResult:
         """Match a corpus against the bank -> :class:`ScanResult` (P, D)."""
-        enc = self._encode_docs(docs)
-        D = len(enc)
-        hits = np.zeros((self.n_patterns, D), dtype=bool)
         spec_stats: SpeculationStats | None = None
-        with obs.span("scanner.scan", patterns=self.n_patterns, docs=D):
+        with obs.span("scanner.scan", patterns=self.n_patterns) as sp, \
+                _scan_totals() as moved:
             self.last_trace_id = obs.current_trace_id() or self.last_trace_id
+            with obs.span("scanner.encode"):
+                enc = self._encode_docs(docs)
+            D = len(enc)
+            residues = sum(len(e) for e in enc)
+            hits = np.zeros((self.n_patterns, D), dtype=bool)
             # Batch docs of equal length together (one fixed-shape program
             # each).
             by_len: dict = {}
             for d, e in enumerate(enc):
                 by_len.setdefault(len(e), []).append(d)
             for L, idxs in sorted(by_len.items()):
-                corpus = np.stack([enc[d] for d in idxs]) if L else \
-                    np.zeros((len(idxs), 0), dtype=np.int32)
+                with obs.span("scanner.stack", docs=len(idxs), length=L):
+                    corpus = np.stack([enc[d] for d in idxs]) if L else \
+                        np.zeros((len(idxs), 0), dtype=np.int32)
                 for g in self.groups:
+                    maps = None
                     if g.mode == "speculative" and L:
                         finals, st = self._group_doc_finals(g, corpus)
                         spec_stats = st if spec_stats is None \
                             else spec_stats.merged(st)
+                    elif L:
+                        maps = self._group_doc_mappings(g, corpus)
                     else:
-                        if L:
-                            maps = self._group_doc_mappings(g, corpus)
-                        else:
-                            maps = np.broadcast_to(
-                                np.arange(g.n, dtype=np.int32),
-                                (len(g.indices), len(idxs), g.n),
-                            )
-                        starts = g.bank.starts                  # (Pg,)
-                        finals = np.take_along_axis(
-                            maps, starts[:, None, None].astype(np.int64),
-                            axis=2
-                        )[:, :, 0]                              # (Pg, Dg)
-                    acc = np.take_along_axis(
-                        g.bank.accepting, finals.astype(np.int64), axis=1
-                    )
-                    hits[np.ix_(g.indices, np.asarray(idxs))] = acc
+                        maps = np.broadcast_to(
+                            np.arange(g.n, dtype=np.int32),
+                            (len(g.indices), len(idxs), g.n),
+                        )
+                    with obs.span("scanner.select", patterns=len(g.indices),
+                                  docs=len(idxs)):
+                        if maps is not None:
+                            starts = g.bank.starts              # (Pg,)
+                            finals = np.take_along_axis(
+                                maps, starts[:, None, None].astype(np.int64),
+                                axis=2
+                            )[:, :, 0]                          # (Pg, Dg)
+                        acc = np.take_along_axis(
+                            g.bank.accepting, finals.astype(np.int64), axis=1
+                        )
+                        hits[np.ix_(g.indices, np.asarray(idxs))] = acc
+            if sp is not None:
+                sp.attrs.update(docs=D, residues=residues,
+                                h2d_bytes=moved[0], d2h_bytes=moved[1])
         obs.counter("engine.scans").inc()
         obs.counter("engine.docs_scanned").inc(D)
+        obs.counter("engine.residues_scanned").inc(residues)
         self.last_speculation = spec_stats
         return ScanResult(hits=hits, ids=self.ids, speculation=spec_stats)
 
@@ -787,9 +852,13 @@ class Scanner:
                 )
         for g in self.groups:
             maps = self._group_doc_mappings(g, blocks)[:, :B]  # (Pg, B, n)
-            wmaps = np.asarray(X.sliding_window_mappings(
-                jnp.asarray(maps), m
-            ))                                              # (Pg, W, n)
+            with obs.span("scanner.device", mode="windows",
+                          patterns=len(g.indices), docs=W, length=window
+                          ) as sp:
+                wmaps = np.asarray(X.sliding_window_mappings(
+                    jnp.asarray(maps), m
+                ))                                          # (Pg, W, n)
+                _count_moved(sp, maps.nbytes, wmaps.nbytes)
             finals = np.take_along_axis(
                 wmaps, g.bank.starts[:, None, None].astype(np.int64), axis=2
             )[:, :, 0]

@@ -10,12 +10,17 @@ helpers so instrumentation sites stay one-liners::
         ...
     print(obs.render_prometheus(obs.snapshot()))
 
-Observability is **enabled by default** (overhead is a handful of counter
-increments and perf_counter reads per request — measured <2% on warm scans
-by ``benchmarks/bench_obs.py``). ``obs.disable()`` turns every mutator into
-a single attribute-check early return and ``obs.span`` into a shared no-op
-context manager; scan/construct results are bit-identical either way
-(asserted in ``tests/test_obs.py``).
+Observability is **enabled by default**: a handful of counter increments,
+spans and perf_counter reads per call. Measured on one TPU v5 lite in the
+benchmark's ``prosite23-batch.swissprot`` cell (about 35 spans and one
+flight record per 1,024-sequence shard of ~1.4 s), the medians of the
+window rate with obs on, with ``obs.disable()`` and with the profiler
+tracing too lie within the cell's run-to-run noise (about 1%; the runs are
+in PERF.md, section 6).
+``obs.disable()`` turns every mutator into a single attribute-check early
+return and ``obs.span`` into a shared no-op context manager; scan/construct
+results are bit-identical either way (asserted in ``tests/test_obs.py`` and
+``tests/test_scan_spans.py``).
 
 ``obs.configure(xla_annotations=True)`` additionally bridges each span into
 ``jax.profiler.TraceAnnotation`` so spans appear on the host timeline of

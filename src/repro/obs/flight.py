@@ -36,6 +36,10 @@ from pathlib import Path
 
 from .export import snapshot_record, span_records, write_jsonl
 
+#: Spans that read state and move nothing: the recorder's own writes and a
+#: corpus job's checkpoint probes. Alone they never make a ``force=False``
+#: record write; they go out with the next record that does.
+QUIET_SPANS = frozenset({"flight.record", "jobs.pending"})
 
 def _live_obs():
     # Lazy: repro.obs imports this module while initializing, so the
@@ -83,28 +87,33 @@ class FlightRecorder:
     # -- recording -----------------------------------------------------------
 
     def record(self, *, label: str | None = None, force: bool = True,
-               **extra) -> dict | None:
+               trace_id: str | None = None, **extra) -> dict | None:
         """Append one delta record (+ the spans finished since the last
         record). ``extra`` keys land on the record top-level (the corpus job
         stamps ``shard=``). ``force=False`` skips the write when nothing
-        moved (the periodic tick's idle case). -> the metrics record, or
-        None if skipped."""
+        moved (the periodic tick's idle case); spans in ``QUIET_SPANS``
+        alone do not count as movement. A write is one ``flight.record``
+        span, in ``trace_id`` when given (the corpus job passes its own).
+        -> the metrics record, or None if skipped."""
         obs = _live_obs()
         with self._lock:
             cur = obs.snapshot()
             delta = obs.snapshot_delta(self._last_snap, cur)
-            self._last_snap = cur
             spans = [s for s in obs.recent_spans(1 << 30)
                      if s.span_id > self._last_span_id]
+            if not force and not delta and all(
+                    s.name in QUIET_SPANS for s in spans):
+                # Nothing to write: the quiet spans stay for the next record.
+                return None
+            self._last_snap = cur
             if spans:
                 self._last_span_id = max(s.span_id for s in spans)
-            if not force and not delta and not spans:
-                return None
-            rec = snapshot_record(delta, label=label if label is not None
-                                  else self.label, kind="flight")
-            rec.update(extra)
-            self._rotate_if_needed()
-            write_jsonl(self.path, [rec] + span_records(spans))
+            with obs.span("flight.record", trace_id=trace_id):
+                rec = snapshot_record(delta, label=label if label is not None
+                                      else self.label, kind="flight")
+                rec.update(extra)
+                self._rotate_if_needed()
+                write_jsonl(self.path, [rec] + span_records(spans))
             return rec
 
     def _rotate_if_needed(self) -> None:

@@ -183,8 +183,10 @@ class CorpusJob:
 
     def pending(self) -> list:
         """Shard indices not yet validly checkpointed, in scan order."""
-        return [s for s in range(self.manifest.n_shards)
-                if not self._shard_ready(s)]
+        with obs.span("jobs.pending", trace_id=self.trace_id,
+                      shards=self.manifest.n_shards):
+            return [s for s in range(self.manifest.n_shards)
+                    if not self._shard_ready(s)]
 
     @property
     def complete(self) -> bool:
@@ -204,7 +206,8 @@ class CorpusJob:
             # Flush anything that moved since the last record (other work
             # between construction and run) into a non-shard record, so
             # each shard record below is the shard's work alone.
-            self.flight.record(label="jobs.pre_run", force=False)
+            self.flight.record(label="jobs.pre_run", force=False,
+                               trace_id=self.trace_id)
             if self.flight.interval_s is not None:
                 self.flight.start()
         try:
@@ -218,9 +221,10 @@ class CorpusJob:
                                       stream_threshold=self.stream_threshold)
                     path = self._shard_path(shard)
                     tmp = path.with_suffix(f".tmp.{os.getpid()}")
-                    with open(tmp, "wb") as f:
-                        np.savez(f, hits=hits)
-                    os.replace(tmp, path)   # commit point
+                    with obs.span("jobs.checkpoint", shard=shard):
+                        with open(tmp, "wb") as f:
+                            np.savez(f, hits=hits)
+                        os.replace(tmp, path)   # commit point
                 obs.counter("jobs.shards_scanned",
                             help="corpus shards scanned to completion").inc()
                 # Deterministic per-shard quantities: these move by exactly
@@ -233,7 +237,8 @@ class CorpusJob:
                               help="items per scanned shard"
                               ).observe(stop - start)
                 if self.flight is not None:
-                    self.flight.record(shard=shard, items=stop - start)
+                    self.flight.record(shard=shard, items=stop - start,
+                                       trace_id=self.trace_id)
                 scanned += 1
         finally:
             if self.flight is not None:
