@@ -232,20 +232,67 @@ def match_bank_parallel(tables: jnp.ndarray, symbols: jnp.ndarray,
     return M.reduce(FN, mappings, axis=1)      # (P, n)
 
 
+def _select_rows(cols, v):
+    """``v'[q, w] = cols[v[q, w], w]`` without a gather: a binary tree of
+    selects over the bits of ``v``, each level halving the candidate rows.
+    (n, R, 128), (n, R, 128) -> (n, R, 128); n - 1 selects and ceil(log2 n)
+    bit tests per element. Row ``j`` of ``cols`` is a slice of its leading
+    axis, so every operand lines up with ``v`` lane for lane."""
+    cands = [cols[j][None] for j in range(cols.shape[0])]
+    bit = 0
+    while len(cands) > 1:
+        odd = ((v >> bit) & 1) == 1
+        cands = [jnp.where(odd, cands[i + 1], cands[i]) if i + 1 < len(cands)
+                 else cands[i] for i in range(0, len(cands), 2)]
+        bit += 1
+    return jnp.broadcast_to(cands[0], v.shape)
+
+
 def _bank_doc_mappings(tables, corpus, n_chunks):
     """Enumeration final mapping of every (pattern, doc): -> (P, D, n).
 
-    All (pattern, doc, chunk) cells compute in one doubly-vmapped batch over
-    the flattened ``(D * n_chunks)`` chunk axis; composition is one monoid
-    reduce over the chunk axis, batched over patterns x docs.
+    One scan over the chunks' symbols, time-major, advances every (pattern,
+    chunk) lane at once. Lane ``w = p * C + c`` (``C = D * n_chunks``)
+    carries pattern ``p``'s chunk ``c``, and the ``W = P * C`` lanes pad to
+    a multiple of 128. The carry holds the n tracked states of every lane
+    as ``(n, lanes / 128, 128)``: the states lie outside the (8, 128) tile,
+    so each row of ``cols`` is a whole slab that lines up with the carry
+    (with the states on sublanes, XLA:TPU copies every row out on its own).
+    Each symbol step uses no gather:
+
+        cols[j, w] = tables[p, j, sym[c]]   # rows @ onehot(p * k + sym)
+        v'[q, w]   = cols[v[q, w], w]       # _select_rows
+
+    The first is one exact contraction (0/1 one-hot against state ids
+    below 2^24, at ``Precision.HIGHEST``, as the Pallas kernels do); the
+    second is compares and selects only. The padding lanes are cropped.
+    Composition is one monoid reduce over the chunk axis, batched over
+    patterns x docs.
     """
     D, L = corpus.shape
-    chunks = corpus.reshape(D * n_chunks, L // n_chunks)
-    fns = jax.vmap(
-        lambda t: jax.vmap(lambda c: chunk_mapping_enumeration(t, c))(chunks)
-    )(tables)                                  # (P, D * n_chunks, n)
-    Pn, _, n = fns.shape
-    return M.reduce(FN, fns.reshape(Pn, D, n_chunks, n), axis=2)
+    Pn, n, k = tables.shape
+    C = D * n_chunks
+    W = Pn * C
+    Wp = -(-W // 128) * 128
+    syms = corpus.reshape(C, L // n_chunks).T               # (T, C)
+    keys = (jnp.arange(Pn, dtype=jnp.int32)[:, None] * k
+            + syms[:, None, :]).reshape(-1, W)               # (T, W)
+    keys = jnp.pad(keys, ((0, 0), (0, Wp - W)))
+    rows = jnp.transpose(tables, (1, 0, 2)).reshape(n, Pn * k)
+    rows = rows.astype(jnp.float32)                          # [j, p*k + s]
+    v0 = jax.lax.broadcasted_iota(jnp.int32, (n, Wp // 128, 128), 0)
+
+    def step(v, key):
+        onehot = (jax.lax.broadcasted_iota(jnp.int32, (Pn * k, Wp), 0)
+                  == key[None, :]).astype(jnp.float32)
+        cols = jnp.dot(rows, onehot, precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+        cols = cols.astype(jnp.int32).reshape(v.shape)
+        return _select_rows(cols, v), None
+
+    v, _ = jax.lax.scan(step, v0, keys)
+    fns = v.reshape(n, Wp)[:, :W].reshape(n, Pn, D, n_chunks)
+    return M.reduce(FN, jnp.transpose(fns, (1, 2, 3, 0)), axis=2)
 
 
 @functools.partial(jax.jit, static_argnames=("n_chunks",))

@@ -27,7 +27,7 @@ CONSTRUCTION_BUCKETINGS = ("auto", "size", "off")
 
 #: Default SFA state budget for ``mode="auto"``: patterns whose exact SFA
 #: closes within this many states get the paper's single-lookup inner loop;
-#: the rest fall back to enumeration (Mytkowicz-style n-wide gathers). 512
+#: the rest fall back to enumeration (Mytkowicz-style, all n states). 512
 #: splits the bundled PROSITE bank into a representative mix of both.
 DEFAULT_SFA_STATE_BUDGET = 512
 
@@ -45,7 +45,7 @@ class ChunkPolicy:
         program (and one VMEM-resident table in the Pallas inner loop).
     ``bucket`` / ``bucket_edges``
         size-bucketing of the pattern bank: patterns are grouped so no
-        pattern pays gathers more than ~2x wider than its own automaton
+        pattern tracks more than ~2x the states of its own automaton
         (``core.multipattern.bucket_by_size``'s padding argument).
     """
 
@@ -252,10 +252,12 @@ class SpeculationPolicy:
         the ``auto``-mode tier threshold: a pattern whose SFA blows the
         state budget routes to speculation only when its DFA has at least
         this many states; smaller blowup patterns keep the enumeration
-        fallback (their n-wide gathers are already cheap). 128 is a
-        conservative bound on the measured crossover
+        fallback. 128 is a conservative bound on the crossover measured
+        on the CPU against the gathered enumeration step
         (``BENCH_speculative.json``): warm repeat scans win well below it,
-        but a first scan also pays the sequential profiling pass.
+        but a first scan also pays the sequential profiling pass. The XLA
+        enumeration step no longer gathers, so that crossover is stale
+        until it is measured again on the chip (ROADMAP A8).
     """
 
     m: int = 8
@@ -306,7 +308,7 @@ class ScanPlan:
     ``mode``
         ``"sfa"`` forces the paper's SFA matching (construction must fit the
         budget for *every* pattern, else ``StateBlowup`` propagates);
-        ``"enumeration"`` forces the related-work all-states gather mode;
+        ``"enumeration"`` forces the related-work all-states mode;
         ``"speculative"`` forces the hot-state speculation executor
         (:mod:`repro.speculative` — m speculated boundary states per chunk,
         validate + repair, bit-identical to enumeration by construction);

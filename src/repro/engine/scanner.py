@@ -64,6 +64,8 @@ obs.counter("engine.h2d_bytes",
             help="bytes of scan inputs sent to the device")
 obs.counter("engine.d2h_bytes",
             help="bytes of scan results read back from the device")
+obs.counter("engine.enum_pattern_residues",
+            help="pattern x residue steps the enumeration executor ran")
 obs.counter("speculative.total_chunks",
             help="chunks executed speculatively")
 obs.counter("speculative.hit_chunks",
@@ -236,8 +238,8 @@ def _resolve_sfas(ids, dfas, plan: ScanPlan):
                 f"{budget}-state budget and "
                 "mode='sfa' forbids the enumeration fallback"
             ) from None
-        # auto's blowup tier: large automata go speculative (their n-wide
-        # enumeration gathers are what speculation exists to avoid); small
+        # auto's blowup tier: large automata go speculative (tracking all n
+        # states per chunk is what speculation exists to avoid); small
         # blowup patterns keep the enumeration fallback.
         if dfas[i].n_states >= plan.speculation.auto_states:
             return "speculative"
@@ -351,6 +353,15 @@ def _count_moved(span, h2d: int, d2h: int) -> None:
     if total is not None:
         total[0] += h2d
         total[1] += d2h
+
+
+def _count_enumeration(span, pattern_residues: int) -> None:
+    """One enumeration round trip's work, ``patterns x docs x head
+    length``, on its ``scanner.device`` span and on the registry's
+    ``engine.enum_pattern_residues``."""
+    obs.counter("engine.enum_pattern_residues").inc(pattern_residues)
+    if span is not None:
+        span.attrs.update(pattern_residues=pattern_residues)
 
 
 @contextlib.contextmanager
@@ -567,6 +578,8 @@ class Scanner:
                 out = X.bank_doc_mappings(g.tables, corpus_j, n_chunks)
             maps = np.asarray(out)
             _count_moved(sp, head.nbytes, maps.nbytes)
+            if g.mode == "enumeration":
+                _count_enumeration(sp, len(g.indices) * D * L)
         return maps
 
     # -- the speculative core ----------------------------------------------
@@ -725,6 +738,7 @@ class Scanner:
                             g.tables, jnp.asarray(sub), n_chunks,
                         ))
                         _count_moved(sp, sub.nbytes, maps.nbytes)
+                        _count_enumeration(sp, Pg * len(bad) * head_len)
                     exact = np.take_along_axis(
                         maps, starts[:, None, None].astype(np.int64), axis=2
                     )[:, :, 0]

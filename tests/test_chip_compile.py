@@ -5,9 +5,11 @@ Interpret mode cannot see what the chip's compiler refuses (a reduction it
 does not lower, a block shape off the (8, 128) tiling, a dynamic slice of a
 value); these compiles can, at the shapes the scan service really runs:
 the 23-signature bundled bank (n_max = 87, k = 20), construction tiles of
-128 frontier rows, and 8,192 chunks of 46 symbols. Every test asserts the
-compiled program holds a Mosaic kernel (``tpu_custom_call``), i.e. the
-kernel was compiled, not interpreted.
+128 frontier rows, and 8,192 chunks of 46 symbols. Every kernel test
+asserts the compiled program holds a Mosaic kernel (``tpu_custom_call``),
+i.e. the kernel was compiled, not interpreted. The XLA enumeration
+executor compiles at the batch cell's shard shapes with no gather in its
+per-symbol loop.
 
 The topology is described inside a module fixture, never at import, so
 every pytest-xdist worker collects the same tests and only the worker that
@@ -108,3 +110,18 @@ def test_construction_round_compiles_for_v5e(one_chip):
              ((P, cap), U32), ((P, cap, K), I32), ((P,), I32), ((P,), I32),
              ((P, W, 2), U32), ((P, 4), U32), ((P, W), U32),
              ((bucket,), I32), ((bucket,), jnp.bool_))
+
+
+@pytest.mark.parametrize("Pg,n", [(2, 57), (1, 87)])
+def test_enumeration_executor_compiles_for_v5e(one_chip, Pg, n):
+    """``bank_doc_mappings`` for a 1,024 x 448 shard (8,192 chunks of 56
+    symbols) at the batch cell's enumeration groups: the loop that carries
+    the (n, lanes / 128, 128) tracked states holds no gather."""
+    from _hlo import loop_body
+    from repro.engine.executors import bank_doc_mappings
+
+    args = [jax.ShapeDtypeStruct(s, I32, sharding=one_chip)
+            for s in ((Pg, n, K), (1024, 448))]
+    text = bank_doc_mappings.lower(*args, 8).compile().as_text()
+    body = loop_body(text, f"s32[{n},{Pg * 8192 // 128},128]")
+    assert body and not [ln for ln in body if "gather(" in ln]

@@ -169,6 +169,34 @@ def test_transfer_bytes_are_the_shapes_sent_and_read_back(scanner):
     assert moved["engine.residues_scanned"] == residues
 
 
+def test_enumeration_round_trips_count_their_pattern_residues(scanner):
+    """Each enumeration ``scanner.device`` span carries ``pattern_residues``
+    = patterns x docs x head length, and ``engine.enum_pattern_residues``
+    moves by the sum; the other modes' spans carry none."""
+    docs = _docs(24)
+    n_chunks = scanner.plan.chunking.n_chunks
+    want = sum(len(g.indices) * D * (L - L % n_chunks)
+               for L, D in Counter(len(d) for d in docs).items()
+               for g in scanner.groups if g.mode == "enumeration")
+    assert want > 0
+    before = obs.snapshot("engine")
+    mark = _mark()
+    scanner.scan(docs)
+    moved = obs.snapshot_delta(before, obs.snapshot("engine"))
+    device = [s for s in _spans_after(mark) if s.name == "scanner.device"]
+    enum = [s for s in device if s.attrs["mode"] == "enumeration"]
+    assert len(enum) == len(LENGTHS) * sum(g.mode == "enumeration"
+                                           for g in scanner.groups)
+    assert sum(s.attrs["pattern_residues"] for s in enum) == want
+    assert all(s.attrs["pattern_residues"]
+               == s.attrs["patterns"] * s.attrs["docs"] * s.attrs["length"]
+               for s in enum)
+    assert not [s for s in device
+                if s.attrs["mode"] != "enumeration"
+                and "pattern_residues" in s.attrs]
+    assert moved["engine.enum_pattern_residues"] == want
+
+
 def test_census_windows_counts_both_round_trips(scanner):
     n_chunks = scanner.plan.chunking.n_chunks
     stride, window = 2 * n_chunks, 4 * n_chunks

@@ -166,6 +166,35 @@ def test_repair_bound_falls_back_to_enumeration():
     assert rs.speculation.repair_rounds == 1
 
 
+def test_fallback_counts_its_enumeration_work():
+    """The fallback's ``scanner.device`` span is an enumeration round trip:
+    its ``pattern_residues`` is patterns x fallback lanes x head length,
+    and ``engine.enum_pattern_residues`` moves by it."""
+    from repro import obs
+
+    dfa = _two_state_dfa()
+    docs = _random_docs(1, 4, 80, 4)
+    plan = ScanPlan(
+        mode="speculative",
+        speculation=SpeculationPolicy(
+            m=2, profile_source=np.asarray([2, 3]), max_repair_rounds=1
+        ),
+    )
+    sc = Scanner.compile([dfa], plan)
+    obs.enable()
+    before = obs.snapshot("engine")
+    mark = max((s.span_id for s in obs.recent_spans(1 << 20)), default=0)
+    rs = sc.scan(docs)
+    moved = obs.snapshot_delta(before, obs.snapshot("engine"))
+    (fb,) = [s for s in obs.recent_spans(1 << 20) if s.span_id > mark
+             and s.name == "scanner.device"
+             and s.attrs["mode"] == "enumeration"]
+    assert rs.speculation.fallback_lanes > 0
+    assert fb.attrs["pattern_residues"] == 1 * fb.attrs["docs"] * 80 > 0
+    assert moved["engine.enum_pattern_residues"] == \
+        fb.attrs["pattern_residues"]
+
+
 def test_perfect_profile_hits_everything():
     """Speculating *all* states is a perfect profile: hit rate 1, zero
     repair rounds (the stats invariant's other edge)."""
