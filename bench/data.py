@@ -1,15 +1,15 @@
-"""Seeded inputs for every cell: protein lengths, residues and user patterns.
+"""Seeded inputs for every cell: sequence lengths, symbols and user patterns.
 
 Nothing here imports the program. Every size is a function of the
 configuration alone, so all seeds share one set of shapes (and one set of
-compiled programs); ``--seed`` changes residues, order and pattern variants.
+compiled programs); ``--seed`` changes symbols, order and pattern variants.
 
-* Lengths: log-normal (configuration ``proteins``), truncated to ``clip``,
+* Lengths: log-normal (configuration ``sequences``), truncated to ``clip``,
   quantised to length classes with geometric edges. A class's length is the
   truncated distribution's mean inside the class, and its share the
   probability mass there, both integrated on a fixed grid.
-* Residues: i.i.d. from the configuration's composition, drawn in bulk as
-  bytes (no per-character Python).
+* Symbols: i.i.d. from the configuration's ``alphabet`` with its
+  ``composition``, drawn in bulk as bytes (no per-character Python).
 * Cold patterns: variants of real PROSITE signatures (see :func:`variant`).
 """
 
@@ -20,24 +20,26 @@ import re
 
 import numpy as np
 
-ALPHABET = "ACDEFGHIKLMNPQRSTVWY"
+#: The letters a cold pattern's new residue class draws from: PROSITE
+#: signatures are over the 20 amino acids.
+VARIANT_AMINO_ACIDS = "ACDEFGHIKLMNPQRSTVWY"
 
 
-def _grid(prot: dict):
-    mu, sigma = math.log(prot["median"]), prot["sigma"]
+def _grid(seqs: dict):
+    mu, sigma = math.log(seqs["median"]), seqs["sigma"]
     xs = np.exp(np.linspace(0.0, math.log(1e6), 1_000_001))
     pdf = np.exp(-(np.log(xs) - mu) ** 2 / (2 * sigma * sigma)) / (
         xs * sigma * math.sqrt(2 * math.pi))
     return xs, np.gradient(xs) * pdf
 
 
-def length_classes(prot: dict) -> dict:
+def length_classes(seqs: dict) -> dict:
     """-> {"lengths", "shares", "removed_entries", "removed_residues",
     "mean"}: class lengths (ints), entry shares per class (sum 1 after the
     clip), and the shares of entries and residues that the clip removes."""
-    xs, w = _grid(prot)
-    lo, hi = prot["clip"]
-    edges = prot["class_edges"]
+    xs, w = _grid(seqs)
+    lo, hi = seqs["clip"]
+    edges = seqs["class_edges"]
     keep = (xs >= lo) & (xs <= hi)
     lengths, shares = [], []
     for i, (a, b) in enumerate(zip(edges[:-1], edges[1:])):
@@ -52,6 +54,15 @@ def length_classes(prot: dict) -> dict:
         "removed_residues": float(1 - (w * xs)[keep].sum() / (w * xs).sum()),
         "mean": float((w * xs)[keep].sum() / w[keep].sum()),
     }
+
+
+def clip_info(seqs: dict) -> str:
+    """The info line that says what the clip of ``seqs`` cuts away."""
+    cls = length_classes(seqs)
+    return (f"reduced: the clip to {seqs['clip']} removes "
+            f"{cls['removed_entries']:.6%} of entries and "
+            f"{cls['removed_residues']:.6%} of residues; class lengths "
+            f"{cls['lengths']}")
 
 
 def allocate(shares, total: int) -> list:
@@ -79,15 +90,17 @@ def interleave(counts) -> list:
 
 
 def composition(cfg: dict) -> np.ndarray:
-    comp = cfg["proteins"]["composition"]
-    p = np.asarray([comp[a] for a in ALPHABET], dtype=np.float64)
+    """Probabilities of the configuration's symbols, in ``alphabet`` order."""
+    comp = cfg["sequences"]["composition"]
+    p = np.asarray([comp[a] for a in cfg["alphabet"]], dtype=np.float64)
     return p / p.sum()
 
 
 def residues(rng: np.random.Generator, n_docs: int, length: int,
-             probs: np.ndarray) -> list:
-    """``n_docs`` protein strings of ``length`` residues, drawn in bulk."""
-    codes = np.frombuffer(ALPHABET.encode("ascii"), dtype=np.uint8)
+             probs: np.ndarray, alphabet: str) -> list:
+    """``n_docs`` strings of ``length`` symbols of ``alphabet`` (``probs``
+    in its order), drawn in bulk."""
+    codes = np.frombuffer(alphabet.encode("ascii"), dtype=np.uint8)
     cdf = np.cumsum(probs)
     cdf[-1] = 1.0
     idx = np.searchsorted(cdf, rng.random(n_docs * length), side="right")
@@ -132,7 +145,7 @@ def variant(pattern: str, kind: str, rng: np.random.Generator):
             return None
         i = sites[int(rng.integers(len(sites)))]
         aa, rep = _RESIDUE.match(elems[i]).groups()
-        others = [a for a in ALPHABET if a != aa]
+        others = [a for a in VARIANT_AMINO_ACIDS if a != aa]
         extra = rng.choice(others, size=int(rng.integers(1, 5)), replace=False)
         elems[i] = "[" + "".join(sorted([aa, *extra])) + "]" + (rep or "")
     else:
@@ -140,7 +153,7 @@ def variant(pattern: str, kind: str, rng: np.random.Generator):
     return head + "-".join(elems) + tail
 
 
-def draw_lengths(prot: dict, rng: np.random.Generator, n: int,
+def draw_lengths(seqs: dict, rng: np.random.Generator, n: int,
                  spec: dict | None = None) -> np.ndarray:
     """``n`` lengths as a traffic mix asks for them (``spec``):
 
@@ -151,13 +164,13 @@ def draw_lengths(prot: dict, rng: np.random.Generator, n: int,
     """
     spec = spec or {"mode": "classes"}
     if spec["mode"] == "classes":
-        cls = length_classes(prot)
+        cls = length_classes(seqs)
         pick = rng.choice(len(cls["shares"]), size=n, p=cls["shares"])
         return np.asarray(cls["lengths"])[pick]
     if spec["mode"] != "raw":
         raise ValueError(f"unknown length mode {spec['mode']!r}")
-    lo, hi = spec.get("range", prot["clip"])
-    xs, w = _grid(prot)
+    lo, hi = spec.get("range", seqs["clip"])
+    xs, w = _grid(seqs)
     keep = (xs >= lo) & (xs <= hi)
     cdf = np.cumsum(w[keep])
     idx = np.searchsorted(cdf, rng.random(n) * cdf[-1], side="right")

@@ -14,7 +14,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import reference, spec
+from bench import data, reference, spec
 
 #: Seeds of warm-up traffic lie far from any seed a run is given.
 WARMUP_SEED_OFFSET = 0x5EED_0000_0000
@@ -28,6 +28,7 @@ class Driver:
         self.shard_docs = self.cfg["deployment"]["shard_docs"]
         self.gen = spec.generator(self.traffic["generator"],
                                   Path(c["bench"]))
+        self.info = [data.clip_info(self.cfg["sequences"])]
 
     def setup(self) -> None:
         from repro.scanservice import CorpusManifest, ScanService
@@ -83,14 +84,17 @@ class Driver:
         return {"t0": t0, "t_end": t_end, "shards": shards,
                 "attempted": len(shards) + failed, "failed": failed,
                 "in_window_generation_s": gen_s,
-                "residues": sum(s["residues"] for s in shards)}
+                "residues": sum(s["residues"] for s in shards),
+                "info": self.info}
 
     def close(self) -> None:
         self.svc.close()
 
     def check(self, control: bool) -> tuple:
         """-> (compared numbers, info). A sample of the documents of every
-        finished shard, drawn from the seed, against the reference."""
+        finished shard, drawn from the seed, against the reference; under
+        ``control`` the reference on the configuration's control fold in the
+        program's place."""
         rng = np.random.default_rng(self.seed + 1)
         per = max(1, math.ceil(self.traffic["check_docs"]
                                / max(1, len(self.shards))))
@@ -112,10 +116,13 @@ class Driver:
             got.append(hits[:, pick])
             docs += [cur["docs"][lo + i] for i in pick]
         want = reference.hits(self.pats, docs)
-        have = (reference.hits(self.pats, [reference.fold4(d) for d in docs])
+        have = (reference.hits(
+                    self.pats, [reference.control(self.cfg, d) for d in docs])
                 if control else
                 (np.concatenate(got, axis=1) if got else want[:, :0]))
         bad = int((have != want).sum()) if have.shape == want.shape \
             else int(want.size)
-        return ({"hit_mismatches": bad, "answers_missing": missing},
-                {"docs_checked": len(docs), "hits_expected": int(want.sum())})
+        info = {"docs_checked": len(docs), "hits_expected": int(want.sum())}
+        if control:
+            info["control"] = self.cfg["control"]["what"]
+        return {"hit_mismatches": bad, "answers_missing": missing}, info

@@ -14,7 +14,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import reference, spec
+from bench import data, reference, spec
 from bench.drivers.corpus_job import WARMUP_SEED_OFFSET
 
 
@@ -73,6 +73,7 @@ class Driver:
         self.dep = self.cfg["deployment"]
         self.gen = spec.generator(self.traffic["generator"],
                                   Path(c["bench"]))
+        self.info = [data.clip_info(self.cfg["sequences"])]
 
     def setup(self) -> None:
         from repro.scanservice import ScanService
@@ -115,13 +116,16 @@ class Driver:
         w["generator_late_s"] = {
             "median": statistics.median(late) if late else 0.0,
             "max": max(late) if late else 0.0}
+        w["info"] = self.info
         return w
 
     def close(self) -> None:
         self.svc.close()
 
     def check(self, control: bool) -> tuple:
-        """Every request's demuxed slice against the reference."""
+        """Every request's demuxed slice against the reference; under
+        ``control`` the reference on the configuration's control fold in the
+        program's place."""
         bad = missing = n_docs = expected = 0
         for r in self.sent:
             want = reference.hits(r["reference"], r["docs"])
@@ -129,7 +133,8 @@ class Driver:
             n_docs += len(r["docs"])
             if control:
                 have = reference.hits(
-                    r["reference"], [reference.fold4(d) for d in r["docs"]])
+                    r["reference"],
+                    [reference.control(self.cfg, d) for d in r["docs"]])
             elif "result" in r:
                 have = np.asarray(r["result"].hits, dtype=bool)
             else:
@@ -137,6 +142,8 @@ class Driver:
                 continue
             bad += int((have != want).sum()) if have.shape == want.shape \
                 else int(want.size)
-        return ({"hit_mismatches": bad, "answers_missing": missing},
-                {"requests_checked": len(self.sent), "docs_checked": n_docs,
-                 "hits_expected": expected})
+        info = {"requests_checked": len(self.sent), "docs_checked": n_docs,
+                "hits_expected": expected}
+        if control:
+            info["control"] = self.cfg["control"]["what"]
+        return {"hit_mismatches": bad, "answers_missing": missing}, info

@@ -58,7 +58,7 @@ def requests(cfg: dict, traffic: dict, seed: int, seconds: float,
     lo, hi = traffic["sequences_per_request"]
     n_seqs = tpl.integers(lo, hi + 1, size=n)
     lengths = np.split(
-        data.draw_lengths(cfg["proteins"], tpl, int(n_seqs.sum()),
+        data.draw_lengths(cfg["sequences"], tpl, int(n_seqs.sum()),
                           traffic.get("lengths")),
         np.cumsum(n_seqs)[:-1])
     pats = traffic.get("patterns", {"kind": "bank"})
@@ -87,7 +87,8 @@ def requests(cfg: dict, traffic: dict, seed: int, seconds: float,
     for j, i in enumerate(order):
         docs = []
         for length in lengths[i]:
-            docs += data.residues(rng, 1, int(length), probs)
+            docs += data.residues(rng, 1, int(length), probs,
+                                  cfg["alphabet"])
         if pats["kind"] == "variant":
             p = [fresh_variant(sources, int(first[i]), kinds[i], rng, seen)]
         elif pats["kind"] == "subset":

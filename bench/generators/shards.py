@@ -21,7 +21,7 @@ from bench import data
 
 
 def class_plan(cfg: dict, traffic: dict) -> dict:
-    cls = data.length_classes(cfg["proteins"])
+    cls = data.length_classes(cfg["sequences"])
     counts = data.allocate(cls["shares"], traffic["cycle_shards"])
     return {"classes": cls, "counts": counts, "order": data.interleave(counts)}
 
@@ -35,7 +35,7 @@ def cycle_lengths(cfg: dict, traffic: dict, shard_docs: int,
                          shard_docs)
     tpl = np.random.default_rng([traffic["template_seed"], cycle])
     n = traffic["cycle_shards"] * shard_docs
-    lengths = data.draw_lengths(cfg["proteins"], tpl, n,
+    lengths = data.draw_lengths(cfg["sequences"], tpl, n,
                                 traffic.get("lengths", {"mode": "raw"}))
     return lengths[np.random.default_rng([seed, cycle, 1]).permutation(n)]
 
@@ -48,7 +48,8 @@ def docs_of(cfg: dict, lengths, rng: np.random.Generator) -> list:
     out = []
     for run in np.split(lengths, cut):
         if len(run):
-            out += data.residues(rng, len(run), int(run[0]), probs)
+            out += data.residues(rng, len(run), int(run[0]), probs,
+                                 cfg["alphabet"])
     return out
 
 

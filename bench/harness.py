@@ -5,6 +5,8 @@ The configuration's deployment kind names the driver,
 open loop), and the traffic file names the generator of its inputs,
 ``bench/generators/<name>.py``. Drivers only hand the program generated
 inputs; everything they compare against comes from :mod:`bench.reference`.
+Of the configuration the harness reads only ``deployment`` and ``checks``;
+what else a run has to say comes from the driver's window (``info`` lines).
 """
 
 from __future__ import annotations
@@ -16,7 +18,7 @@ import time
 from collections import deque
 from pathlib import Path
 
-from . import data, spec
+from . import spec
 from .trace_reduce import WINDOW, reduce_trace
 
 OUT = spec.BENCH / "out"
@@ -208,7 +210,6 @@ def execute(name: str, seed: int, seconds: float, trace: bool, *,
         result["breakdown"] = red["breakdown"]
     result["checks"] = checks
 
-    cls = data.length_classes(c["config"]["proteins"])
     info += [
         f"device: {d0.platform} {d0.device_kind} x {len(devices)}",
         f"setup: {setup_s:.3f} s (process start to the first timed request); "
@@ -220,10 +221,7 @@ def execute(name: str, seed: int, seconds: float, trace: bool, *,
         f"persistent-cache loads, {compiles['seconds']:.3f} s",
         f"work: attempted {w['attempted']}, succeeded "
         f"{w['attempted'] - w['failed']}, failed {w['failed']}",
-        f"reduced: the clip to {c['config']['proteins']['clip']} removes "
-        f"{cls['removed_entries']:.6%} of entries and "
-        f"{cls['removed_residues']:.6%} of residues; class lengths "
-        f"{cls['lengths']}",
+        *w.get("info", ()),
     ]
     if "generator_late_s" in w:
         g = w["generator_late_s"]
@@ -234,9 +232,7 @@ def execute(name: str, seed: int, seconds: float, trace: bool, *,
         info.append(f"closed loop: {len(w['shards'])} shards, "
                     f"{w['residues']} residues; corpus generated inside the "
                     f"window: {w['in_window_generation_s']:.3f} s")
-    info.append(f"check: {check_info}, {check_s:.3f} s"
-                + (" (control: the reference on 4-bit residues)"
-                   if control else ""))
+    info.append(f"check: {check_info}, {check_s:.3f} s")
     info += [f"compared {k}: {v['value']} (limit {v['limit']})"
              for k, v in checks.items()]
     (out / "last_run.json").write_text(json.dumps(

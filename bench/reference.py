@@ -2,9 +2,10 @@
 
 Independent of the program: it shares no code, tables or automata with it,
 only the PROSITE syntax (https://prosite.expasy.org/scanprosite/scanprosite_doc.html):
-``x`` any residue, ``[..]`` one of, ``{..}`` none of, ``e(n)`` / ``e(n,m)``
+``x`` any symbol, ``[..]`` one of, ``{..}`` none of, ``e(n)`` / ``e(n,m)``
 repeats, ``<`` / ``>`` anchors at the N / C terminus, hit = some match
-anywhere in the sequence (ScanProsite's search semantics).
+anywhere in the sequence (ScanProsite's search semantics). The letters are
+whatever the configuration's alphabet holds.
 
 Documents are joined by newlines and each pattern runs once over the whole
 text with ``re.finditer``; no element matches a newline, so no match spans
@@ -60,13 +61,9 @@ def hits(patterns, docs) -> np.ndarray:
     return out
 
 
-#: The control's lowered precision: residues stored in 4 bits, 16 codes for
-#: 20 amino acids, so four of them share another's code: S and G that of A,
-#: T and K that of R. (Dropping the top bit of the alphabet-order code, T V
-#: W Y onto A C D E, reads no mismatch at all on some seeds of the cold
-#: cell, whose window checks only ~80 answers.)
-FOLD = str.maketrans("STGK", "ARAR")
-
-
-def fold4(doc: str) -> str:
-    return doc.translate(FOLD)
+def control(cfg: dict, doc: str) -> str:
+    """``doc`` as the configuration's control reads it: ``control.fold`` is
+    ``[from, to]``, each symbol of ``from`` replaced by the one at its place
+    in ``to`` (a lowered precision: several symbols share one code)."""
+    src, dst = cfg["control"]["fold"]
+    return doc.translate(str.maketrans(src, dst))

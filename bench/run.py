@@ -10,8 +10,9 @@ one JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
 ``device`` (and with ``--trace 1`` the ``breakdown``), then ``checks``.
 
 ``--control 1`` puts the control in the program's place for the check: the
-reference computed on residues stored in 4 bits. It must come out not
-correct. Without a TPU, or with fewer chips than the cell asks for, the run
+reference computed on the documents as the configuration's ``control`` fold
+reads them (for the protein cells, residues stored in 4 bits). It must come
+out not correct. Without a TPU, or with fewer chips than the cell asks for, the run
 exits 3 and prints no result.
 """
 

@@ -36,7 +36,7 @@ FAULTS = ("answer_altered", "half_batch", "state_unchanged")
 def small(c: dict) -> None:
     """The cell at a size a test run holds: lengths up to 640, short
     windows, low rates."""
-    p = c["config"]["proteins"]
+    p = c["config"]["sequences"]
     p["clip"] = [20, 640]
     p["class_edges"] = [20, 40, 80, 160, 320, 640]
     if c["config"]["deployment"]["kind"] == "corpus_job":

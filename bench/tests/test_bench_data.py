@@ -11,7 +11,7 @@ CFG = files("prosite23-web", "poisson")["config"]
 
 
 def test_length_classes_follow_swissprot():
-    cls = data.length_classes(CFG["proteins"])
+    cls = data.length_classes(CFG["sequences"])
     assert cls["lengths"] == [34, 65, 125, 236, 444, 837, 1590, 2978]
     assert abs(sum(cls["shares"]) - 1) < 1e-9
     assert 355 < cls["mean"] < 365
@@ -20,7 +20,7 @@ def test_length_classes_follow_swissprot():
 
 
 def test_allocate_and_interleave():
-    cls = data.length_classes(CFG["proteins"])
+    cls = data.length_classes(CFG["sequences"])
     counts = data.allocate(cls["shares"], 64)
     assert counts == [0, 1, 10, 24, 21, 7, 1, 0]
     order = data.interleave(counts)
@@ -30,10 +30,11 @@ def test_allocate_and_interleave():
 
 def test_residues_in_bulk_follow_the_composition():
     rng = np.random.default_rng(7)
-    docs = data.residues(rng, 200, 500, data.composition(CFG))
+    docs = data.residues(rng, 200, 500, data.composition(CFG),
+                         CFG["alphabet"])
     assert len(docs) == 200 and {len(d) for d in docs} == {500}
     text = "".join(docs)
-    assert set(text) <= set(data.ALPHABET)
+    assert set(text) <= set(CFG["alphabet"])
     assert abs(text.count("L") / len(text) - 0.0966) < 0.005
 
 

@@ -1,5 +1,6 @@
 """The plain reference agrees with the program's sequential oracle at a
-tiny size, and its control (4-bit residues) does not."""
+tiny size, and its control (the configuration's fold: 4-bit residues) does
+not."""
 
 import numpy as np
 import pytest
@@ -7,7 +8,9 @@ import pytest
 from bench import data, reference
 from bench.tests.cells import files
 
-BANK = files("prosite23-web", "poisson")["config"]["bank"]
+CFG = files("prosite23-web", "poisson")["config"]
+BANK = CFG["bank"]
+ALPHABET = CFG["alphabet"]
 
 
 @pytest.mark.parametrize("pattern,regex", [
@@ -28,7 +31,8 @@ def test_reference_equals_census_sequential():
 
     rng = np.random.default_rng(11)
     probs = np.full(20, 1 / 20)
-    docs = data.residues(rng, 40, 97, probs) + data.residues(rng, 7, 5, probs)
+    docs = (data.residues(rng, 40, 97, probs, ALPHABET)
+            + data.residues(rng, 7, 5, probs, ALPHABET))
     bank = PatternBank.from_patterns(BANK)
     sc = Scanner.compile(bank, backend="reference")
     enc = [sc.encode(d) for d in docs]
@@ -45,12 +49,12 @@ def test_reference_equals_census_sequential():
 
 def test_control_departs_from_the_reference():
     rng = np.random.default_rng(12)
-    docs = data.residues(rng, 50, 300, np.full(20, 1 / 20))
+    docs = data.residues(rng, 50, 300, np.full(20, 1 / 20), ALPHABET)
     pats = list(BANK.values())
     good = reference.hits(pats, docs)
-    ctl = reference.hits(pats, [reference.fold4(d) for d in docs])
+    ctl = reference.hits(pats, [reference.control(CFG, d) for d in docs])
     assert (good != ctl).sum() > 0
-    assert len(set("".join(reference.fold4(d) for d in docs))) == 16
+    assert len(set("".join(reference.control(CFG, d) for d in docs))) == 16
 
 
 def test_documents_never_share_a_match():

@@ -32,7 +32,7 @@ def test_workload_files_parse(w, tmp_path):
     assert c["config"]["name"] == w["config"]
     assert c["workload"]["chips"] in (1, 4)
     for key in ("source", "assumed", "reduced", "guarantees", "checks",
-                "bank", "proteins", "deployment"):
+                "bank", "alphabet", "sequences", "control", "deployment"):
         assert key in c["config"], key
     names = {m["name"] for m in c["end_to_end"]}
     assert "setup_s" in names and len(names) >= 2
@@ -60,6 +60,29 @@ def test_config_entries_match_files():
         assert cfg["name"] == c["name"]
         assert set(c["reduced"]) <= set(cfg["reduced"])
         assert NAME.match(c["name"])
+
+
+#: What a PROSITE-syntax signature may hold besides the alphabet's letters.
+PROSITE_SYNTAX = set("x-[]{}()<>,0123456789")
+
+
+@pytest.mark.parametrize("c", BM["configs"] + PLANNED["configs"],
+                         ids=lambda c: c["name"])
+def test_config_declares_its_alphabet_and_control(c):
+    """The alphabet, the length model's composition, the bank and the
+    control agree, whatever the alphabet is."""
+    cfg = json.loads((spec.ROOT / c["file"]).read_text())
+    alphabet = cfg["alphabet"]
+    assert alphabet and len(set(alphabet)) == len(alphabet)
+    assert sorted(cfg["sequences"]["composition"]) == sorted(alphabet)
+    for key in ("median", "sigma", "clip", "class_edges"):
+        assert key in cfg["sequences"], key
+    for sid, pattern in cfg["bank"].items():
+        assert set(pattern) <= set(alphabet) | PROSITE_SYNTAX, sid
+    src, dst = cfg["control"]["fold"]
+    assert len(src) == len(dst) and cfg["control"]["what"]
+    assert set(src) | set(dst) <= set(alphabet)
+    assert any(a != b for a, b in zip(src, dst))
 
 
 def test_moves_names_an_end_to_end_metric_of_each_cell():
