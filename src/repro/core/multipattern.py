@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .dfa import DFA
+from .regex import AMINO_ACIDS
 
 
 # --------------------------------------------------------------------------
@@ -116,18 +117,20 @@ class PatternBank:
         )
 
     @classmethod
-    def from_patterns(cls, patterns: Mapping[str, str] | Sequence[str]
-                      ) -> "PatternBank":
-        """Compile PROSITE signatures (id -> pattern mapping, or a list)."""
+    def from_patterns(cls, patterns: Mapping[str, str] | Sequence[str],
+                      alphabet: str = AMINO_ACIDS) -> "PatternBank":
+        """Compile PROSITE signatures (id -> pattern mapping, or a list)
+        over ``alphabet``."""
         from .prosite import compile_prosite
 
         if isinstance(patterns, Mapping):
             ids = tuple(patterns.keys())
-            dfas = [compile_prosite(patterns[i]) for i in ids]
+            pats = [patterns[i] for i in ids]
         else:
             ids = tuple(f"pattern_{p}" for p in range(len(patterns)))
-            dfas = [compile_prosite(p) for p in patterns]
-        return cls.from_dfas(dfas, ids)
+            pats = list(patterns)
+        return cls.from_dfas(
+            [compile_prosite(p, alphabet=alphabet) for p in pats], ids)
 
     def device_arrays(self):
         """(tables, accepting, starts) as jnp arrays, ready for the matchers."""

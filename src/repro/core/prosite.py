@@ -3,10 +3,10 @@
 PROSITE patterns (https://prosite.expasy.org, the paper's benchmark source)
 use a syntax of ``-``-separated elements:
 
-  ``A``        a literal amino acid
-  ``x``        any amino acid
-  ``[ALT]``    any of the listed residues
-  ``{AM}``     any residue *except* those listed
+  ``A``        a literal symbol
+  ``x``        any symbol
+  ``[ALT]``    any of the listed symbols
+  ``{AM}``     any symbol *except* those listed
   ``e(n)``     element repeated exactly ``n`` times
   ``e(n,m)``   element repeated ``n``..``m`` times
   ``<``        pattern anchored at the N-terminus (string start)
@@ -14,7 +14,9 @@ use a syntax of ``-``-separated elements:
 
 We translate to the framework regex syntax (``core.regex``) and compile to a
 minimal, complete DFA with *search* semantics unless ``<`` anchors the start
-(matching ScanProsite behaviour).
+(matching ScanProsite behaviour). The symbols are the 20 amino acids unless
+an ``alphabet`` is given: the same syntax over ``"ACGT"`` writes DNA sites
+(IUPAC ``N`` as ``x``, ``Y`` as ``[CT]``).
 """
 
 from __future__ import annotations
@@ -39,8 +41,9 @@ class PrositePattern:
     anchored_end: bool
 
 
-def translate(pattern: str) -> PrositePattern:
-    """Translate PROSITE syntax to framework regex syntax."""
+def translate(pattern: str, alphabet: str = AMINO_ACIDS) -> PrositePattern:
+    """Translate PROSITE syntax to framework regex syntax; every literal and
+    class member must be a symbol of ``alphabet``."""
     raw = pattern.strip().rstrip(".")
     body = raw
     anchored_start = body.startswith("<")
@@ -58,7 +61,7 @@ def translate(pattern: str) -> PrositePattern:
         if not elem:
             raise PrositeSyntaxError(f"empty element in {pattern!r}")
         base, rep = _split_repeat(elem)
-        out.append(_translate_element(base, pattern) + rep)
+        out.append(_translate_element(base, pattern, alphabet) + rep)
     return PrositePattern(
         raw=raw,
         regex="".join(out),
@@ -82,41 +85,45 @@ def _split_repeat(elem: str) -> tuple:
     return elem, ""
 
 
-def _translate_element(base: str, pattern: str) -> str:
+def _translate_element(base: str, pattern: str, alphabet: str) -> str:
     if base == "x":
         return "."
     if base.startswith("[") and base.endswith("]"):
         members = base[1:-1]
-        _check_members(members, pattern)
+        _check_members(members, pattern, alphabet)
         return f"[{members}]"
     if base.startswith("{") and base.endswith("}"):
         members = base[1:-1]
-        _check_members(members, pattern)
+        _check_members(members, pattern, alphabet)
         return f"[^{members}]"
-    if len(base) == 1 and base in AMINO_ACIDS:
+    if len(base) == 1:
+        _check_members(base, pattern, alphabet)
         return base
     raise PrositeSyntaxError(f"bad element {base!r} in {pattern!r}")
 
 
-def _check_members(members: str, pattern: str) -> None:
+def _check_members(members: str, pattern: str, alphabet: str) -> None:
     if not members:
         raise PrositeSyntaxError(f"empty class in {pattern!r}")
     for c in members:
-        if c not in AMINO_ACIDS:
-            raise PrositeSyntaxError(f"residue {c!r} not an amino acid in {pattern!r}")
+        if c not in alphabet:
+            raise PrositeSyntaxError(
+                f"symbol {c!r} not in the alphabet {alphabet!r} in {pattern!r}")
 
 
-def compile_prosite(pattern: str, *, minimize_dfa: bool = True) -> DFA:
-    """Compile a PROSITE pattern to a minimal complete search DFA."""
-    tr = translate(pattern)
+def compile_prosite(pattern: str, *, alphabet: str = AMINO_ACIDS,
+                    minimize_dfa: bool = True) -> DFA:
+    """Compile a PROSITE pattern over ``alphabet`` to a minimal complete
+    search DFA."""
+    tr = translate(pattern, alphabet)
     regex = tr.regex
     if not tr.anchored_start:
         regex = "(.*)(" + regex + ")"
-    if tr.anchored_end:
-        # End-anchored: accepting only at string end — no absorbing accept.
-        dfa = subset_construct(compile_nfa(regex, AMINO_ACIDS))
-    else:
-        dfa = _make_accepting_absorbing(subset_construct(compile_nfa(regex, AMINO_ACIDS)))
+    dfa = subset_construct(compile_nfa(regex, alphabet))
+    if not tr.anchored_end:
+        # A match anywhere accepts for good; an end-anchored pattern
+        # accepts only at the string's end, so its accept does not absorb.
+        dfa = _make_accepting_absorbing(dfa)
     return minimize(dfa) if minimize_dfa else dfa
 
 

@@ -18,6 +18,9 @@ single-lookup inner loop), and returns a scanner exposing:
 * ``stream(blocks)`` — corpora far larger than memory, fed as chunk blocks
   through the backend inner loop while the running function-monoid prefix
   carries across calls (see :mod:`repro.engine.streaming`);
+* ``stream_docs(docs)`` — many long documents streamed side by side, one
+  round trip per block step for all of them (the corpus job's long
+  documents);
 * ``mapping(doc)`` / ``accepts(doc)`` / ``locate(doc, pattern)`` helpers.
 
 Every backend (``reference`` / ``xla`` / ``pallas``) and every mode computes
@@ -42,6 +45,7 @@ from ..construction import SFA, StateBlowup, construct_bank
 from ..core.bucketing import partition_by_size
 from ..core.dfa import DFA
 from ..core.multipattern import PatternBank
+from ..core.regex import AMINO_ACIDS
 from ..speculative import (
     HotStateProfile,
     SpeculationStats,
@@ -66,6 +70,10 @@ obs.counter("engine.d2h_bytes",
             help="bytes of scan results read back from the device")
 obs.counter("engine.enum_pattern_residues",
             help="pattern x residue steps the enumeration executor ran")
+obs.counter("engine.stream_blocks",
+            help="full (n_chunks, block_len) blocks stream() sent")
+obs.counter("engine.stream_residues",
+            help="symbols of the inputs stream() matched")
 obs.counter("speculative.total_chunks",
             help="chunks executed speculatively")
 obs.counter("speculative.hit_chunks",
@@ -84,9 +92,10 @@ obs.gauge("speculative.hit_rate",
 # --------------------------------------------------------------------------
 
 
-def _compile_one(spec: Any) -> DFA:
-    """One pattern spec -> DFA. Strings resolve as: bundled PROSITE id,
-    then PROSITE signature syntax, then framework regex."""
+def _compile_one(spec: Any, alphabet: str) -> DFA:
+    """One pattern spec -> DFA. Strings resolve, over ``alphabet``, as:
+    bundled PROSITE id, then PROSITE signature syntax, then framework
+    regex."""
     from ..core.dfa import compile_dfa
     from ..core.prosite import (
         PROSITE_EXTRA,
@@ -100,31 +109,32 @@ def _compile_one(spec: Any) -> DFA:
     if isinstance(spec, str):
         pool = {**PROSITE_SAMPLES, **PROSITE_EXTRA}
         if spec in pool:
-            return compile_prosite(pool[spec])
+            return compile_prosite(pool[spec], alphabet=alphabet)
         try:
-            return compile_prosite(spec)
+            return compile_prosite(spec, alphabet=alphabet)
         except PrositeSyntaxError:
-            return compile_dfa(spec)
+            return compile_dfa(spec, alphabet)
     raise TypeError(
         f"cannot compile pattern spec of type {type(spec).__name__}; "
         "expected str, DFA, PatternBank, or a sequence/mapping of those"
     )
 
 
-def _normalize(patterns: Any) -> tuple:
-    """-> (ids, dfas, single) where ``single`` marks a one-pattern input."""
+def _normalize(patterns: Any, alphabet: str) -> tuple:
+    """-> (ids, dfas, single) where ``single`` marks a one-pattern input;
+    strings compile over ``alphabet``."""
     if isinstance(patterns, PatternBank):
         return (tuple(patterns.ids),
                 [patterns.dfa(p) for p in range(patterns.n_patterns)], False)
     if isinstance(patterns, (str, DFA)):
-        dfa = _compile_one(patterns)
+        dfa = _compile_one(patterns, alphabet)
         pid = patterns if isinstance(patterns, str) else "pattern_0"
         return (pid,), [dfa], True
     if isinstance(patterns, Mapping):
         ids = tuple(patterns.keys())
-        return ids, [_compile_one(patterns[i]) for i in ids], False
+        return ids, [_compile_one(patterns[i], alphabet) for i in ids], False
     if isinstance(patterns, Sequence):
-        dfas = [_compile_one(p) for p in patterns]
+        dfas = [_compile_one(p, alphabet) for p in patterns]
         ids = tuple(
             p if isinstance(p, str) else f"pattern_{i}"
             for i, p in enumerate(patterns)
@@ -341,6 +351,12 @@ _scan_moved: contextvars.ContextVar = contextvars.ContextVar(
 )
 
 
+def _stream_attrs(stream: bool) -> dict:
+    """A stream block's round trips carry ``path="stream"``; the scan's
+    spans carry no ``path``."""
+    return {"path": "stream"} if stream else {}
+
+
 def _count_moved(span, h2d: int, d2h: int) -> None:
     """One device round trip's bytes, on its ``scanner.device`` span, on the
     registry's ``engine.h2d_bytes`` / ``engine.d2h_bytes``, and on the
@@ -362,6 +378,17 @@ def _count_enumeration(span, pattern_residues: int) -> None:
     obs.counter("engine.enum_pattern_residues").inc(pattern_residues)
     if span is not None:
         span.attrs.update(pattern_residues=pattern_residues)
+
+
+def _count_speculation(stats: SpeculationStats) -> None:
+    """One speculative pass's stats on the registry's ``speculative.*``."""
+    obs.counter("speculative.total_chunks").inc(stats.total_chunks)
+    obs.counter("speculative.hit_chunks").inc(stats.hit_chunks)
+    obs.counter("speculative.repaired_chunks").inc(stats.repaired_chunks)
+    obs.counter("speculative.repair_rounds").inc(stats.repair_rounds)
+    obs.counter("speculative.fallback_lanes").inc(stats.fallback_lanes)
+    if stats.total_chunks:
+        obs.gauge("speculative.hit_rate").set(stats.hit_rate)
 
 
 @contextlib.contextmanager
@@ -409,18 +436,22 @@ class Scanner:
 
     @classmethod
     def compile(cls, patterns: Any, plan: ScanPlan | None = None,
-                **overrides) -> "Scanner":
+                alphabet: str | None = None, **overrides) -> "Scanner":
         """Compile patterns under a plan (``overrides`` patch plan fields,
-        so ``Scanner.compile(bank, mode="sfa")`` works without a ScanPlan)."""
+        so ``Scanner.compile(bank, mode="sfa")`` works without a ScanPlan).
+        String patterns compile over ``alphabet`` (default: the 20 amino
+        acids); compiled DFAs and banks must already be over it."""
         plan = (plan or ScanPlan()).with_(**overrides) if overrides else \
             (plan or ScanPlan()).validate()
-        ids, dfas, single = _normalize(patterns)
+        ids, dfas, single = _normalize(patterns, alphabet or AMINO_ACIDS)
         if not dfas:
             raise ValueError("empty pattern set")
-        alphabet = dfas[0].alphabet
+        alphabet = alphabet or dfas[0].alphabet
         for d in dfas:
             if d.alphabet != alphabet:
-                raise ValueError("all patterns must share one alphabet")
+                raise ValueError(
+                    f"all patterns must share one alphabet ({alphabet!r}); "
+                    f"got one over {d.alphabet!r}")
 
         trace_id = None
         with obs.span("scanner.compile", patterns=len(dfas),
@@ -493,7 +524,11 @@ class Scanner:
 
     def encode(self, text: str) -> np.ndarray:
         sym = {c: i for i, c in enumerate(self.alphabet)}
-        return np.asarray([sym[c] for c in text], dtype=np.int32)
+        try:
+            return np.asarray([sym[c] for c in text], dtype=np.int32)
+        except KeyError as e:
+            raise ValueError(f"symbol {e.args[0]!r} is not in the scanner's "
+                             f"alphabet {self.alphabet!r}") from None
 
     def _encode_docs(self, docs) -> list:
         if isinstance(docs, str):
@@ -539,26 +574,34 @@ class Scanner:
                     )
         return maps
 
+    def _device_mesh(self, D: int, stream: bool):
+        """The mesh a round trip of ``D`` docs runs on: the plan's for the
+        scan, none for a stream block (one device's worth of work)."""
+        if stream or self.mesh is None:
+            return None
+        n_dev = int(np.prod(list(self.mesh.shape.values())))
+        if D % n_dev:
+            raise ValueError(
+                f"shard_map distribution needs doc count ({D}) divisible "
+                f"by the mesh's {self.plan.data_axis} size ({n_dev})"
+            )
+        return self.mesh
+
     def _head_mappings(self, g: PatternGroup, head: np.ndarray,
-                       n_chunks: int) -> np.ndarray:
+                       n_chunks: int, stream: bool = False) -> np.ndarray:
         """Chunk-parallel mappings of the head: one ``scanner.device`` span
         from the upload to the read-back (none on the reference backend,
-        which never leaves the host)."""
+        which never leaves the host). A stream block's span carries
+        ``path="stream"``."""
         backend = self.plan.backend
         if backend == "reference":
             return _reference_doc_mappings(g.bank.tables, head)
         D, L = head.shape
-        if self.mesh is not None:
-            n_dev = int(np.prod(list(self.mesh.shape.values())))
-            if D % n_dev:
-                raise ValueError(
-                    f"shard_map distribution needs doc count ({D}) divisible "
-                    f"by the mesh's {self.plan.data_axis} size ({n_dev})"
-                )
+        mesh = self._device_mesh(D, stream)
         with obs.span("scanner.device", mode=g.mode, patterns=len(g.indices),
-                      docs=D, length=L) as sp:
+                      docs=D, length=L, **_stream_attrs(stream)) as sp:
             corpus_j = jnp.asarray(head)
-            if self.mesh is not None:
+            if mesh is not None:
                 if g.mode == "sfa":
                     out = g._dist_fn(g.deltas, g.sfa_maps, corpus_j)
                 else:
@@ -671,80 +714,95 @@ class Scanner:
         g._spec_profile = states
         return states
 
+    def _speculative_finals(self, g: PatternGroup, spec: np.ndarray,
+                            head: np.ndarray, entry: np.ndarray,
+                            stream: bool = False) -> tuple:
+        """Exact final states of every (pattern-in-group, doc) of ``head``
+        (a multiple of ``n_chunks`` long) from the ``entry`` states, (Pg,)
+        per pattern or (Pg, D) per lane, speculating on the (Pg, m) states
+        ``spec``: (Pg, D) int32 plus the pass's
+        :class:`~repro.speculative.SpeculationStats`.
+
+        The executor only adopts chunk results whose entry state it verified
+        exactly; any lane the repair bound leaves unresolved is recomputed
+        through the enumeration executor (always the local XLA one —
+        exactness makes the backend choice invisible, and the fallback
+        subset's ragged doc count doesn't fit the mesh contract). A stream
+        block's device spans carry ``path="stream"``, and its speculative
+        one the chunk counts too.
+        """
+        pol = self.plan.speculation
+        n_chunks = self.plan.chunking.n_chunks
+        D, L = head.shape
+        Pg = len(g.indices)
+        entry = np.asarray(entry, dtype=np.int32)
+        mesh = self._device_mesh(D, stream)
+        attrs = _stream_attrs(stream)
+        with obs.span("scanner.device", mode=g.mode, patterns=Pg, docs=D,
+                      length=L, **attrs) as sp:
+            args = (jnp.asarray(spec), jnp.asarray(entry), jnp.asarray(head))
+            if mesh is not None:
+                out = g._spec_dist_fn(g.tables, *args)
+            else:
+                out = speculative_bank_finals(
+                    g.tables, *args, n_chunks=n_chunks,
+                    max_rounds=pol.max_repair_rounds,
+                )
+            out = [np.asarray(x) for x in out]
+            _count_moved(sp, spec.nbytes + entry.nbytes + head.nbytes,
+                         sum(x.nbytes for x in out))
+            finals, resolved, hit_n, repaired, rounds = out
+            stats = SpeculationStats(
+                total_chunks=Pg * D * n_chunks,
+                hit_chunks=int(hit_n),
+                repaired_chunks=int(repaired),
+                repair_rounds=int(rounds),
+                fallback_lanes=int(np.sum(~resolved)),
+            )
+            if sp is not None and stream:
+                sp.attrs.update(total_chunks=stats.total_chunks,
+                                hit_chunks=stats.hit_chunks)
+        if not resolved.all():
+            finals = np.array(finals)  # device views are read-only
+            bad = np.flatnonzero(~resolved.all(axis=0))
+            sub = np.ascontiguousarray(head[bad])
+            with obs.span("speculative.fallback", lanes=len(bad)), \
+                    obs.span("scanner.device", mode="enumeration",
+                             patterns=Pg, docs=len(bad), length=L,
+                             **attrs) as sp:
+                maps = np.asarray(X.bank_doc_mappings(
+                    g.tables, jnp.asarray(sub), n_chunks,
+                ))
+                _count_moved(sp, sub.nbytes, maps.nbytes)
+                _count_enumeration(sp, Pg * len(bad) * L)
+            lane_entry = np.broadcast_to(entry.reshape(Pg, -1), (Pg, D))
+            exact = np.take_along_axis(
+                maps, lane_entry[:, bad, None].astype(np.int64), axis=2
+            )[:, :, 0]
+            finals[:, bad] = np.where(resolved[:, bad], finals[:, bad], exact)
+        return finals, stats
+
     def _group_doc_finals(self, g: PatternGroup, corpus: np.ndarray) -> tuple:
         """Speculative path: exact final states of every (pattern-in-group,
         doc) from each pattern's start — (Pg, D) int32 plus the group's
-        :class:`~repro.speculative.SpeculationStats`.
+        :class:`~repro.speculative.SpeculationStats`, under one
+        ``speculative.scan`` span that carries the chunk counts.
 
         Bit-identical to reading the enumeration mappings off at the start
-        states: the executor only adopts chunk results whose entry state it
-        verified exactly, and any lane the repair bound leaves unresolved is
-        recomputed here through the enumeration executor (always the local
-        XLA one — exactness makes the backend choice invisible, and the
-        fallback subset's ragged doc count doesn't fit the mesh contract).
-        The ragged tail advances the finals sequentially, mirroring
-        ``_group_doc_mappings``.
+        states (see :meth:`_speculative_finals`). The ragged tail advances
+        the finals sequentially, mirroring ``_group_doc_mappings``.
         """
-        pol = self.plan.speculation
         n_chunks = self.plan.chunking.n_chunks
         D, L = corpus.shape
         head_len = L - (L % n_chunks)
         starts = g.bank.starts.astype(np.int32)
-        Pg = len(g.indices)
         stats = SpeculationStats()
-        with obs.span("speculative.scan", patterns=Pg, docs=D):
+        with obs.span("speculative.scan", patterns=len(g.indices),
+                      docs=D) as sp:
             if head_len:
-                spec = self._speculation_profile(g, corpus)
-                head = corpus[:, :head_len]
-                if self.mesh is not None:
-                    n_dev = int(np.prod(list(self.mesh.shape.values())))
-                    if D % n_dev:
-                        raise ValueError(
-                            f"shard_map distribution needs doc count ({D}) "
-                            f"divisible by the mesh's {self.plan.data_axis} "
-                            f"size ({n_dev})"
-                        )
-                with obs.span("scanner.device", mode=g.mode, patterns=Pg,
-                              docs=D, length=head_len) as sp:
-                    args = (jnp.asarray(spec), jnp.asarray(starts),
-                            jnp.asarray(head))
-                    if self.mesh is not None:
-                        out = g._spec_dist_fn(g.tables, *args)
-                    else:
-                        out = speculative_bank_finals(
-                            g.tables, *args, n_chunks=n_chunks,
-                            max_rounds=pol.max_repair_rounds,
-                        )
-                    out = [np.asarray(x) for x in out]
-                    _count_moved(sp, spec.nbytes + starts.nbytes + head.nbytes,
-                                 sum(x.nbytes for x in out))
-                finals, resolved, hit_n, repaired, rounds = out
-                stats = SpeculationStats(
-                    total_chunks=Pg * D * n_chunks,
-                    hit_chunks=int(hit_n),
-                    repaired_chunks=int(repaired),
-                    repair_rounds=int(rounds),
-                    fallback_lanes=int(np.sum(~resolved)),
-                )
-                if not resolved.all():
-                    finals = np.array(finals)  # device views are read-only
-                    bad = np.flatnonzero(~resolved.all(axis=0))
-                    sub = np.ascontiguousarray(head[bad])
-                    with obs.span("speculative.fallback", lanes=len(bad)), \
-                            obs.span("scanner.device", mode="enumeration",
-                                     patterns=Pg, docs=len(bad),
-                                     length=head_len) as sp:
-                        maps = np.asarray(X.bank_doc_mappings(
-                            g.tables, jnp.asarray(sub), n_chunks,
-                        ))
-                        _count_moved(sp, sub.nbytes, maps.nbytes)
-                        _count_enumeration(sp, Pg * len(bad) * head_len)
-                    exact = np.take_along_axis(
-                        maps, starts[:, None, None].astype(np.int64), axis=2
-                    )[:, :, 0]
-                    finals[:, bad] = np.where(
-                        resolved[:, bad], finals[:, bad], exact
-                    )
+                finals, stats = self._speculative_finals(
+                    g, self._speculation_profile(g, corpus),
+                    corpus[:, :head_len], starts)
             else:
                 finals = np.repeat(starts[:, None], D, axis=1)
             if head_len < L:
@@ -752,13 +810,10 @@ class Scanner:
                     finals = X.advance_states_sequential(
                         g.bank.tables, finals, corpus[:, head_len:]
                     )
-        obs.counter("speculative.total_chunks").inc(stats.total_chunks)
-        obs.counter("speculative.hit_chunks").inc(stats.hit_chunks)
-        obs.counter("speculative.repaired_chunks").inc(stats.repaired_chunks)
-        obs.counter("speculative.repair_rounds").inc(stats.repair_rounds)
-        obs.counter("speculative.fallback_lanes").inc(stats.fallback_lanes)
-        if stats.total_chunks:
-            obs.gauge("speculative.hit_rate").set(stats.hit_rate)
+            if sp is not None:
+                sp.attrs.update(total_chunks=stats.total_chunks,
+                                hit_chunks=stats.hit_chunks)
+        _count_speculation(stats)
         return finals, stats
 
     # -- public scan API ----------------------------------------------------
@@ -962,11 +1017,91 @@ class Scanner:
         """Scan one logically-concatenated input delivered as an iterable of
         blocks (strings or encoded int arrays) without whole-corpus
         residency. Equivalent to ``scan`` on the concatenation; the running
-        function-monoid prefix carries across fixed-shape block calls."""
-        sess = self.open_stream()
-        for b in blocks:
-            sess.feed(b)
-        return sess.finish()
+        function-monoid prefix carries across fixed-shape block calls. One
+        ``scanner.stream`` span holds the input's round trips and tail."""
+        with obs.span("scanner.stream") as sp, _scan_totals() as moved:
+            sess = self.open_stream()
+            for b in blocks:
+                sess.feed(b)
+            res = sess.finish()
+            if sp is not None:
+                sp.attrs.update(docs=1, residues=res.n_symbols,
+                                blocks=sess.blocks, steps=sess.blocks,
+                                h2d_bytes=moved[0], d2h_bytes=moved[1])
+        return res
+
+    def stream_docs(self, docs) -> ScanResult:
+        """Scan many long documents block by block, side by side:
+        :class:`ScanResult` (P, D), bit-identical to ``scan(docs)``.
+
+        Step ``b`` sends block ``b`` of every document that has one as one
+        ``(lanes, n_chunks * block_len)`` corpus, per group one round trip
+        of the document path's executor (``lanes``: ``D`` rounded up to a
+        power of two, so shards of any size share few programs). Each
+        document's running states carry across steps; a document out of
+        full blocks keeps its states. The ragged tails then advance
+        sequentially, batched by tail length. Memory holds one block per
+        lane. One ``scanner.stream`` span (``docs``, ``residues``,
+        ``blocks``: full blocks of all documents, ``steps``, bytes) holds
+        the round trips and tails."""
+        pol = self.plan.chunking
+        n_chunks = pol.n_chunks
+        S = n_chunks * pol.block_len
+        spec_stats: SpeculationStats | None = None
+        with obs.span("scanner.stream") as sp, _scan_totals() as moved:
+            with obs.span("scanner.encode"):
+                enc = self._encode_docs(docs)
+            D = len(enc)
+            lanes = 1 << max(D - 1, 0).bit_length()
+            n_blocks = np.array([len(e) // S for e in enc], dtype=np.int64)
+            steps = int(n_blocks.max()) if D else 0
+            states = [np.repeat(g.bank.starts.astype(np.int32)[:, None],
+                                lanes, axis=1) for g in self.groups]
+            for b in range(steps):
+                live = np.flatnonzero(n_blocks > b)
+                block = np.zeros((lanes, S), dtype=np.int32)
+                for d in live:
+                    block[d] = enc[d][b * S:(b + 1) * S]
+                for gi, g in enumerate(self.groups):
+                    if g.mode == "speculative":
+                        new, st = self._speculative_finals(
+                            g, self._speculation_profile(g, block), block,
+                            states[gi], stream=True)
+                        _count_speculation(st)
+                        spec_stats = st if spec_stats is None \
+                            else spec_stats.merged(st)
+                    else:
+                        bm = self._head_mappings(g, block, n_chunks,
+                                                 stream=True)  # (Pg, lanes, n)
+                        new = np.take_along_axis(
+                            bm, states[gi][:, :, None].astype(np.int64),
+                            axis=2)[:, :, 0]
+                    states[gi][:, live] = new[:, live]
+            tails: dict = {}
+            for d, e in enumerate(enc):
+                if len(e) > n_blocks[d] * S:
+                    tails.setdefault(len(e) - int(n_blocks[d]) * S,
+                                     []).append(d)
+            for T, ds in tails.items():
+                with obs.span("scanner.tail", docs=len(ds), symbols=T):
+                    sym = np.stack([enc[d][len(enc[d]) - T:] for d in ds])
+                    for gi, g in enumerate(self.groups):
+                        states[gi][:, ds] = X.advance_states_sequential(
+                            g.bank.tables, states[gi][:, ds], sym)
+            hits = np.zeros((self.n_patterns, D), dtype=bool)
+            for gi, g in enumerate(self.groups):
+                hits[g.indices] = np.take_along_axis(
+                    g.bank.accepting, states[gi][:, :D].astype(np.int64),
+                    axis=1)
+            residues = sum(len(e) for e in enc)
+            obs.counter("engine.stream_blocks").inc(int(n_blocks.sum()))
+            obs.counter("engine.stream_residues").inc(residues)
+            if sp is not None:
+                sp.attrs.update(docs=D, residues=residues,
+                                blocks=int(n_blocks.sum()), steps=steps,
+                                h2d_bytes=moved[0], d2h_bytes=moved[1])
+        self.last_speculation = spec_stats or self.last_speculation
+        return ScanResult(hits=hits, ids=self.ids, speculation=spec_stats)
 
     # -- introspection ------------------------------------------------------
 

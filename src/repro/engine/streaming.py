@@ -5,21 +5,27 @@ and an associative combine — nothing about it requires the whole input to be
 resident. :class:`StreamSession` exploits that: callers feed arbitrary-sized
 pieces (strings or encoded int arrays) of one logically-concatenated input;
 the session buffers them into fixed-shape ``(n_chunks, block_len)`` chunk
-blocks, pushes each block through the plan's backend inner loop (the Pallas
-``match_bank_chunks_pallas`` kernel when ``backend="pallas"`` — the ROADMAP
-"wire the kernel in" item), and folds the block's transition function into a
-running function-monoid prefix. Memory high-water mark is one block plus the
-``(P, n)`` prefix, independent of corpus length; fixed shapes mean every
-block reuses one compiled program (and one VMEM-resident table block).
+blocks, pushes each block through the document path's executor for each
+group's mode (``bank_doc_mappings`` / ``bank_doc_mappings_sfa`` or their
+Pallas twins, and the speculative executor, on a one-document corpus), and
+folds the block's transition function into a running function-monoid
+prefix. Memory high-water mark is one block plus the ``(P, n)`` prefix,
+independent of corpus length; fixed shapes mean every block reuses one
+compiled program.
+
+Every block round trip opens a ``scanner.device`` span with
+``path="stream"``; ``engine.stream_blocks`` / ``engine.stream_residues``
+count blocks and symbols, and speculative blocks move ``speculative.*``.
 
 ``StreamSession.finish()`` composes any ragged tail sequentially (exact,
 < one block of work) and returns a :class:`StreamResult` whose mapping is
 bit-identical to ``Scanner.mapping`` of the concatenated input.
 
-This is also the corpus-job path for long documents:
-:func:`repro.scanservice.scan_shard` routes any document at or above the
-job's ``stream_threshold`` through a stream session, so shard memory stays
-bounded by one block regardless of document length.
+The corpus-job path for long documents is its many-document form,
+``Scanner.stream_docs``: :func:`repro.scanservice.scan_shard` sends every
+document at or above the job's ``stream_threshold`` through it, block ``b``
+of all of them in one round trip per group, so shard memory stays bounded
+by one block per document regardless of document length.
 """
 
 from __future__ import annotations
@@ -27,14 +33,14 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import TYPE_CHECKING, Any
 
-import jax.numpy as jnp
 import numpy as np
 
-from ..speculative import SpeculationStats, speculative_bank_finals
+from .. import obs
+from ..speculative import SpeculationStats
 from . import executors as X
 
 if TYPE_CHECKING:  # pragma: no cover
-    from .scanner import PatternGroup, Scanner
+    from .scanner import Scanner
 
 
 @dataclass(frozen=True)
@@ -93,7 +99,7 @@ class StreamSession:
             if g.mode == "speculative" else None
             for g in scanner.groups
         ]
-        self._spec_prof = [None] * len(scanner.groups)
+        self._blocks = 0
         self._spec_stats: SpeculationStats | None = None
         self._has_spec = any(
             g.mode == "speculative" for g in scanner.groups
@@ -116,84 +122,40 @@ class StreamSession:
             self._buf = self._buf[self.super_len:]
             self._advance(block)
 
+    @property
+    def blocks(self) -> int:
+        """Full blocks sent to the device so far."""
+        return self._blocks
+
     def _advance(self, block: np.ndarray) -> None:
-        """Fold one full (n_chunks * block_len) block into the prefix."""
-        for gi, g in enumerate(self.scanner.groups):
+        """Fold one full (n_chunks * block_len) block into every group's
+        running prefix (function or states): per group one round trip of
+        the document path's executor for its mode, on a one-document
+        corpus."""
+        from .scanner import _count_speculation
+
+        sc = self.scanner
+        corpus = block[None, :]
+        for gi, g in enumerate(sc.groups):
             if g.mode == "speculative":
-                self._advance_speculative(gi, g, block)
+                # The block's per-pattern entry states are the stream's
+                # current ones; unresolved lanes fall back to enumeration
+                # at them. The hot-state profile is memoized on the group
+                # (advisory: staleness only costs repairs).
+                finals, st = sc._speculative_finals(
+                    g, sc._speculation_profile(g, corpus), corpus,
+                    self._state[gi], stream=True)
+                _count_speculation(st)
+                self._state[gi] = finals[:, 0].astype(np.int32)
+                self._spec_stats = st if self._spec_stats is None \
+                    else self._spec_stats.merged(st)
                 continue
-            bm = self._block_mapping(g, block)              # (Pg, n)
+            bm = sc._head_mappings(g, corpus, self.n_chunks,
+                                   stream=True)[:, 0]        # (Pg, n)
             # combine(prefix, block): apply prefix first, then the block.
             self._prefix[gi] = np.take_along_axis(bm, self._prefix[gi], axis=1)
-
-    def _advance_speculative(self, gi: int, g: "PatternGroup",
-                             block: np.ndarray) -> None:
-        """Advance a speculative group's exact running states through one
-        block: the block is one D=1 "document" whose per-pattern start
-        states are the stream's current states. Unresolved lanes fall back
-        to the block's enumeration mapping applied at the entry state —
-        exact either way. The hot-state profile is resolved once per
-        session, from the first block (it is advisory; staleness only
-        costs repairs)."""
-        sc = self.scanner
-        pol = sc.plan.speculation
-        prof = self._spec_prof[gi]
-        if prof is None:
-            prof = sc._speculation_profile(g, block[None, :])
-            self._spec_prof[gi] = prof
-        out = speculative_bank_finals(
-            g.tables, jnp.asarray(prof), jnp.asarray(self._state[gi]),
-            jnp.asarray(block[None, :]), n_chunks=self.n_chunks,
-            max_rounds=pol.max_repair_rounds,
-        )
-        finals, resolved, hit_n, repaired, rounds = (
-            np.asarray(x) for x in out
-        )
-        st = SpeculationStats(
-            total_chunks=len(g.indices) * self.n_chunks,
-            hit_chunks=int(hit_n),
-            repaired_chunks=int(repaired),
-            repair_rounds=int(rounds),
-            fallback_lanes=int(np.sum(~resolved)),
-        )
-        states = finals[:, 0].astype(np.int32)
-        if not resolved.all():
-            bm = np.asarray(X.match_bank_parallel(
-                g.tables, jnp.asarray(block), self.n_chunks
-            ))
-            rows = np.arange(len(g.indices))
-            exact = bm[rows, self._state[gi]]
-            bad = ~resolved[:, 0]
-            states[bad] = exact[bad]
-        self._state[gi] = states
-        self._spec_stats = st if self._spec_stats is None \
-            else self._spec_stats.merged(st)
-
-    def _block_mapping(self, g: "PatternGroup", block: np.ndarray) -> np.ndarray:
-        backend = self.scanner.plan.backend
-        if backend == "reference":
-            from .scanner import _reference_doc_mappings
-
-            return _reference_doc_mappings(g.bank.tables, block[None, :])[:, 0]
-        if backend == "pallas":
-            corpus = jnp.asarray(block[None, :])
-            if g.mode == "sfa":
-                out = X.bank_doc_mappings_sfa_pallas(
-                    g.deltas, g.sfa_maps, corpus, self.n_chunks
-                )
-            else:
-                out = X.bank_doc_mappings_pallas(g.tables, corpus, self.n_chunks)
-            return np.array(out[:, 0, :])
-        # xla (shard_map distribution still computes blocks locally: a block
-        # is one device's worth of work by construction)
-        syms = jnp.asarray(block)
-        if g.mode == "sfa":
-            out = X.match_bank_parallel_sfa(
-                g.deltas, g.sfa_maps, syms, self.n_chunks
-            )
-        else:
-            out = X.match_bank_parallel(g.tables, syms, self.n_chunks)
-        return np.array(out)
+        self._blocks += 1
+        obs.counter("engine.stream_blocks").inc()
 
     # -- finishing ----------------------------------------------------------
 
@@ -204,17 +166,19 @@ class StreamSession:
         self._finished = True
         sc = self.scanner
         if len(self._buf):
-            for gi, g in enumerate(sc.groups):
-                if g.mode == "speculative":
-                    self._state[gi] = X.advance_states_sequential(
-                        g.bank.tables, self._state[gi][:, None],
-                        self._buf[None, :],
-                    )[:, 0]
-                else:
-                    self._prefix[gi] = X.compose_sequential(
-                        g.bank.tables, self._prefix[gi], self._buf
-                    )
+            with obs.span("scanner.tail", docs=1, symbols=len(self._buf)):
+                for gi, g in enumerate(sc.groups):
+                    if g.mode == "speculative":
+                        self._state[gi] = X.advance_states_sequential(
+                            g.bank.tables, self._state[gi][:, None],
+                            self._buf[None, :],
+                        )[:, 0]
+                    else:
+                        self._prefix[gi] = X.compose_sequential(
+                            g.bank.tables, self._prefix[gi], self._buf
+                        )
             self._buf = np.zeros(0, dtype=np.int32)
+        obs.counter("engine.stream_residues").inc(self._n_symbols)
 
         mapping = None if self._has_spec else np.broadcast_to(
             np.arange(sc.n_max, dtype=np.int32), (sc.n_patterns, sc.n_max)

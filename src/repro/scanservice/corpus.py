@@ -13,8 +13,9 @@ first unfinished shard.
 
 * document shards scan through ``Scanner.scan``, except documents at or
   above ``stream_threshold`` symbols, which go through the engine's
-  streaming path (``Scanner.stream`` — fixed-shape ``(n_chunks, block_len)``
-  blocks, memory high-water mark independent of document length);
+  streaming path side by side (``Scanner.stream_docs`` — each document in
+  fixed-shape ``(n_chunks, block_len)`` blocks, one round trip per block
+  step for all of them, memory high-water mark one block per document);
 * window shards scan through the prefix-scan census
   (``Scanner.census_windows``): each shard re-derives only its own slice of
   the sequence, and every stride-block's transition function is computed
@@ -140,6 +141,7 @@ def scan_shard(scanner: Scanner, manifest: CorpusManifest, shard: int,
     short = [i for i, d in enumerate(docs) if len(d) < thr]
     if short:
         hits[:, short] = scanner.scan([docs[i] for i in short]).hits
-    for i in (i for i, d in enumerate(docs) if len(d) >= thr):
-        hits[:, i] = scanner.stream([docs[i]]).accepted
+    long = [i for i, d in enumerate(docs) if len(d) >= thr]
+    if long:
+        hits[:, long] = scanner.stream_docs([docs[i] for i in long]).hits
     return hits

@@ -84,7 +84,8 @@ class CorpusJob:
                  plan: ScanPlan | None = None,
                  stream_threshold: int | None = None,
                  flight: bool = True,
-                 flight_interval_s: float | None = None):
+                 flight_interval_s: float | None = None,
+                 alphabet: str | None = None):
         self.manifest = manifest
         self.workdir = Path(workdir)
         self.stream_threshold = stream_threshold
@@ -97,7 +98,8 @@ class CorpusJob:
             self.trace_id = sp.trace_id if sp is not None else None
             # Compilation runs through the plan's cache tiers, so a resuming
             # process with a persistent store pays zero construction rounds.
-            self.scanner = Scanner.compile(patterns, plan)
+            # The digest hashes the DFAs, so it tells alphabets apart.
+            self.scanner = Scanner.compile(patterns, plan, alphabet=alphabet)
         self._check_or_write_meta()
         # Flight recorder: created *after* the compile so its delta base
         # excludes construction — shard records then carry exactly shard

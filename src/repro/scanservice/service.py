@@ -160,9 +160,11 @@ class ScanService:
     # -- corpus jobs ---------------------------------------------------------
 
     def corpus_job(self, patterns, manifest: CorpusManifest, workdir,
-                   **kwargs) -> CorpusJob:
-        """A resumable job running under the service's plan (and cache)."""
-        return CorpusJob(patterns, manifest, workdir, plan=self.plan, **kwargs)
+                   alphabet: str | None = None, **kwargs) -> CorpusJob:
+        """A resumable job running under the service's plan (and cache);
+        string patterns compile over ``alphabet`` (default: amino acids)."""
+        return CorpusJob(patterns, manifest, workdir, plan=self.plan,
+                         alphabet=alphabet, **kwargs)
 
     # -- lifecycle -----------------------------------------------------------
 

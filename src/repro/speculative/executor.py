@@ -101,7 +101,8 @@ def _speculative_core(tables, spec_states, starts, corpus,
     C = n_chunks
     Lc = L // C
     chunks = corpus.reshape(D, C, Lc)
-    starts = jnp.broadcast_to(starts.astype(jnp.int32)[:, None], (Pn, D))
+    starts = jnp.broadcast_to(starts.astype(jnp.int32).reshape(Pn, -1),
+                              (Pn, D))
 
     # Stage 1 — the one batched speculative pass: every (pattern, doc, chunk)
     # cell runs from all m speculated states at once. O(L·m) per pattern,
@@ -212,8 +213,9 @@ def speculative_bank_finals(tables: jnp.ndarray, spec_states: jnp.ndarray,
     """Speculative final states of every (pattern, doc).
 
     ``tables`` (P, n, k) padded enumeration tables; ``spec_states`` (P, m)
-    speculated boundary states (a hot-state profile stack); ``starts`` (P,);
-    ``corpus`` (D, L) with ``L`` divisible by ``n_chunks``.
+    speculated boundary states (a hot-state profile stack); ``starts`` (P,)
+    per pattern or (P, D) per lane; ``corpus`` (D, L) with ``L`` divisible
+    by ``n_chunks``.
 
     -> ``(finals (P, D) int32, resolved (P, D) bool, hit_chunks, repaired,
     rounds)``. ``finals[p, d]`` is **exact** wherever ``resolved[p, d]`` —

@@ -5,7 +5,8 @@ Interpret mode cannot see what the chip's compiler refuses (a reduction it
 does not lower, a block shape off the (8, 128) tiling, a dynamic slice of a
 value); these compiles can, at the shapes the scan service really runs:
 the 23-signature bundled bank (n_max = 87, k = 20), construction tiles of
-128 frontier rows, and 8,192 chunks of 46 symbols. Every kernel test
+128 frontier rows, and 8,192 chunks of 46 symbols; and the construction
+rounds of the REBASE bank over DNA (k = 4). Every kernel test
 asserts the compiled program holds a Mosaic kernel (``tpu_custom_call``),
 i.e. the kernel was compiled, not interpreted. The XLA enumeration
 executor compiles at the batch cell's shard shapes with no gather in its
@@ -108,6 +109,27 @@ def test_construction_round_compiles_for_v5e(one_chip):
     _compile(step, one_chip,
              ((P, N, K), I32), ((P, cap, N), I32), ((P, cap), U32),
              ((P, cap), U32), ((P, cap, K), I32), ((P,), I32), ((P,), I32),
+             ((P, W, 2), U32), ((P, 4), U32), ((P, W), U32),
+             ((bucket,), I32), ((bucket,), jnp.bool_))
+
+
+@pytest.mark.parametrize("P,n,bucket", [(55, 8, 55), (10, 189, 10)])
+def test_dna_construction_round_compiles_for_v5e(one_chip, P, n, bucket):
+    """The fused local round over four symbols, Pallas kernels and all, at
+    the REBASE bank's two construction buckets (72 rows over ``"ACGT"``:
+    55 automata of up to 8 states; 10 of up to 189, 9 of which blow up)."""
+    from repro.construction.batched import _make_local_step, round_schedule
+
+    k = 4
+    cap = round_schedule(tile=TILE, n=n, k=k, max_states=512,
+                         P=P).capacities[0]
+    step = _make_local_step(tile=TILE, n=n, k=k, capacity=cap, P=P,
+                            bucket=bucket, fp_backend="pallas",
+                            expand_backend="pallas", interpret=False)
+    W = (n + 1) // 2
+    _compile(step, one_chip,
+             ((P, n, k), I32), ((P, cap, n), I32), ((P, cap), U32),
+             ((P, cap), U32), ((P, cap, k), I32), ((P,), I32), ((P,), I32),
              ((P, W, 2), U32), ((P, 4), U32), ((P, W), U32),
              ((bucket,), I32), ((bucket,), jnp.bool_))
 

@@ -25,6 +25,7 @@ from repro.speculative import (
     profile_hot_states,
     stack_profile_states,
 )
+from repro.speculative.profile import reachable_sinks
 
 ALL_BUNDLED = sorted({**PROSITE_SAMPLES, **PROSITE_EXTRA})
 
@@ -348,6 +349,28 @@ def test_profiler_top_m_and_stacking():
     assert stacked.shape == (1, 5)
     assert stacked.max() <= 3
     assert HotStateProfile.from_json({"garbage": 1}) is None
+
+
+def _search_ab_tables():
+    """Σ*abΣ* over {a, b}: 0 start, 1 after 'a', 2 the accept sink; rows 3
+    and 4 are padding self-loops no start reaches."""
+    t = np.asarray([[1, 0], [1, 2], [2, 2], [3, 3], [4, 4]], dtype=np.int32)
+    return t[None]
+
+
+@pytest.mark.parametrize("sample, want", [
+    # the sample never matches: the sink still takes a lane, first
+    ([1] * 40, [2, 0, 1]),
+    # an early match: the walk restarts, so the 'a' run after it counts
+    ([0, 1] + [0] * 50, [2, 1, 0]),
+])
+def test_profiler_reserves_reachable_sinks(sample, want):
+    tables = _search_ab_tables()
+    assert reachable_sinks(tables, np.asarray([0])).tolist() == \
+        [[False, False, True, False, False]]
+    [prof] = profile_hot_states(tables, np.asarray([0]),
+                                np.asarray(sample, dtype=np.int32), m=3)
+    assert prof.states.tolist() == want
 
 
 # --------------------------------------------------------------------------
