@@ -186,9 +186,8 @@ def compose_sequential(tables: np.ndarray, mapping: np.ndarray,
                        syms: np.ndarray) -> np.ndarray:
     """Extend per-pattern transition functions by ``syms``, one symbol at a
     time: ``m'[p, q] = tables[p, m[p, q], sym]``. (Pg, n, k), (Pg, n), (L,)
-    -> (Pg, n). The exact NumPy twin of the chunk matchers — every ragged
-    tail, stream remainder, and reference-backend path funnels through here
-    so the bit-identity contract has a single sequential implementation.
+    -> (Pg, n). The exact NumPy twin of the chunk matchers for one input:
+    the stream session's remainder and the reference backend fold here.
     """
     rows = np.arange(tables.shape[0])[:, None]
     m = mapping
@@ -202,9 +201,9 @@ def advance_states_sequential(tables: np.ndarray, states: np.ndarray,
     """Advance per-(pattern, doc) *states* through per-doc tail symbols:
     ``s'[p, d] = tables[p, s[p, d], tail[d, t]]`` folded over ``t``.
     (Pg, n, k), (Pg, D), (D, T) -> (Pg, D). The state-vector twin of
-    :func:`compose_sequential` for the speculative path, whose head
-    executor produces final *states* rather than whole mappings — ragged
-    tails advance here, one vectorized gather per tail symbol.
+    :func:`compose_sequential`: every ragged tail of the scan and stream
+    paths advances the final *states* here, one vectorized gather per tail
+    symbol over all documents.
     """
     rows = np.arange(tables.shape[0])[:, None]
     tail = np.asarray(tail)

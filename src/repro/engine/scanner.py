@@ -547,9 +547,12 @@ class Scanner:
                             ) -> np.ndarray:
         """Final mapping of every (pattern-in-group, doc): -> (Pg, D, n).
 
-        The chunk-parallel backend handles the head (the largest prefix
-        divisible by ``n_chunks``); any ragged tail is composed sequentially
-        in NumPy — cheap (< one chunk per doc) and exact.
+        For ``mapping`` and ``census_windows``, which need whole transition
+        functions. The chunk-parallel backend handles the head (the largest
+        prefix divisible by ``n_chunks``); the ragged tail then composes
+        onto the mappings of all documents at once, one fancy-index per
+        tail symbol over (Pg, D, n). ``scan`` needs only the start states'
+        columns and advances those instead (:meth:`_group_doc_finals`).
         """
         n_chunks = self.plan.chunking.n_chunks
         D, L = corpus.shape
@@ -557,21 +560,15 @@ class Scanner:
         Pg, n = len(g.indices), g.n
 
         if head_len:
-            head = corpus[:, :head_len]
-            maps = self._head_mappings(g, head, n_chunks)
+            maps = self._head_mappings(g, corpus[:, :head_len], n_chunks)
         else:
-            maps = np.broadcast_to(
-                np.arange(n, dtype=np.int32), (Pg, D, n)
-            ).copy()
-
+            maps = np.broadcast_to(np.arange(n, dtype=np.int32), (Pg, D, n))
         if head_len < L:
-            if not maps.flags.writeable:
-                maps = maps.copy()
+            tail = corpus[:, head_len:]
+            rows = np.arange(Pg)[:, None, None]
             with obs.span("scanner.tail", docs=D, symbols=L - head_len):
-                for d in range(D):
-                    maps[:, d, :] = X.compose_sequential(
-                        g.bank.tables, maps[:, d, :], corpus[d, head_len:]
-                    )
+                for t in range(tail.shape[1]):
+                    maps = g.bank.tables[rows, maps, tail[None, :, t, None]]
         return maps
 
     def _device_mesh(self, D: int, stream: bool):
@@ -783,38 +780,58 @@ class Scanner:
         return finals, stats
 
     def _group_doc_finals(self, g: PatternGroup, corpus: np.ndarray) -> tuple:
-        """Speculative path: exact final states of every (pattern-in-group,
-        doc) from each pattern's start — (Pg, D) int32 plus the group's
-        :class:`~repro.speculative.SpeculationStats`, under one
-        ``speculative.scan`` span that carries the chunk counts.
+        """Exact final states of every (pattern-in-group, doc) from each
+        pattern's start: (Pg, D) int32, plus the pass's
+        :class:`~repro.speculative.SpeculationStats` for a speculative group
+        (``None`` for the others).
 
-        Bit-identical to reading the enumeration mappings off at the start
-        states (see :meth:`_speculative_finals`). The ragged tail advances
-        the finals sequentially, mirroring ``_group_doc_mappings``.
+        The head (the largest prefix divisible by ``n_chunks``) gives the
+        finals: the start columns of the chunk-parallel mappings, or the
+        speculative executor's states under one ``speculative.scan`` span
+        that carries the chunk counts. The ragged tail then advances the
+        finals of all documents at once (:meth:`_advance_tail`).
         """
         n_chunks = self.plan.chunking.n_chunks
         D, L = corpus.shape
         head_len = L - (L % n_chunks)
+        head, tail = corpus[:, :head_len], corpus[:, head_len:]
         starts = g.bank.starts.astype(np.int32)
+        # Empty documents speculate on nothing: their finals are the starts.
+        if g.mode != "speculative" or not L:
+            if head_len:
+                maps = self._head_mappings(g, head, n_chunks)
+                finals = np.take_along_axis(
+                    maps, starts[:, None, None].astype(np.int64), axis=2
+                )[:, :, 0]
+            else:
+                finals = np.repeat(starts[:, None], D, axis=1)
+            return self._advance_tail(g, finals, tail), None
         stats = SpeculationStats()
         with obs.span("speculative.scan", patterns=len(g.indices),
                       docs=D) as sp:
             if head_len:
                 finals, stats = self._speculative_finals(
-                    g, self._speculation_profile(g, corpus),
-                    corpus[:, :head_len], starts)
+                    g, self._speculation_profile(g, corpus), head, starts)
             else:
                 finals = np.repeat(starts[:, None], D, axis=1)
-            if head_len < L:
-                with obs.span("scanner.tail", docs=D, symbols=L - head_len):
-                    finals = X.advance_states_sequential(
-                        g.bank.tables, finals, corpus[:, head_len:]
-                    )
+            finals = self._advance_tail(g, finals, tail)
             if sp is not None:
                 sp.attrs.update(total_chunks=stats.total_chunks,
                                 hit_chunks=stats.hit_chunks)
         _count_speculation(stats)
         return finals, stats
+
+    @staticmethod
+    def _advance_tail(g: PatternGroup, finals: np.ndarray, tail: np.ndarray
+                      ) -> np.ndarray:
+        """Advance (Pg, D) states through the (D, T) ragged tail: one
+        vectorised gather per tail symbol over all documents, under one
+        ``scanner.tail`` span (none when there is no tail)."""
+        if not tail.shape[1]:
+            return finals
+        with obs.span("scanner.tail", docs=tail.shape[0],
+                      symbols=tail.shape[1]):
+            return X.advance_states_sequential(g.bank.tables, finals, tail)
 
     # -- public scan API ----------------------------------------------------
 
@@ -836,29 +853,14 @@ class Scanner:
                 by_len.setdefault(len(e), []).append(d)
             for L, idxs in sorted(by_len.items()):
                 with obs.span("scanner.stack", docs=len(idxs), length=L):
-                    corpus = np.stack([enc[d] for d in idxs]) if L else \
-                        np.zeros((len(idxs), 0), dtype=np.int32)
+                    corpus = np.stack([enc[d] for d in idxs])
                 for g in self.groups:
-                    maps = None
-                    if g.mode == "speculative" and L:
-                        finals, st = self._group_doc_finals(g, corpus)
+                    finals, st = self._group_doc_finals(g, corpus)
+                    if st is not None:
                         spec_stats = st if spec_stats is None \
                             else spec_stats.merged(st)
-                    elif L:
-                        maps = self._group_doc_mappings(g, corpus)
-                    else:
-                        maps = np.broadcast_to(
-                            np.arange(g.n, dtype=np.int32),
-                            (len(g.indices), len(idxs), g.n),
-                        )
                     with obs.span("scanner.select", patterns=len(g.indices),
                                   docs=len(idxs)):
-                        if maps is not None:
-                            starts = g.bank.starts              # (Pg,)
-                            finals = np.take_along_axis(
-                                maps, starts[:, None, None].astype(np.int64),
-                                axis=2
-                            )[:, :, 0]                          # (Pg, Dg)
                         acc = np.take_along_axis(
                             g.bank.accepting, finals.astype(np.int64), axis=1
                         )
